@@ -17,16 +17,16 @@ noise hits both equally; the comparison is written to
 A ``P = 2`` sweep rides along (rows tagged ``"P": 2``): the same paired
 fused-vs-dense comparison through the mesh network phase — the
 (src_proc, dst_proc)-tiled assembly route vs ``_global_transpose``'s dense
-staging — run in a subprocess with two fake CPU devices
-(``--xla_force_host_platform_device_count`` must be set before jax
-initialises, hence the subprocess).
+staging — in this process, over the first two devices, and skipped with a
+notice where there are fewer.  On a CPU host two devices come from
+``XLA_FLAGS=--xla_force_host_platform_device_count=2``, set before the
+benchmark starts.
 """
 
 from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -34,103 +34,76 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import ContextLayout, ContextStore, Pems, PemsConfig, analysis
+from repro.launch.mesh import make_mesh_auto
 from .common import emit
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 V = 16
 
-_P2_SCRIPT = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-import json, time
-import jax, jax.numpy as jnp
-from repro.core import ContextLayout, ContextStore, Pems, PemsConfig
-
-V, P = {v}, 2
-mesh = jax.make_mesh((P,), ("vp",))
-rows = []
-for n_words in {sizes!r}:
-    omega = n_words // (V * V)
-    lo = (ContextLayout()
-          .add("send", (V, omega), jnp.int32)
-          .add("recv", (V, omega), jnp.int32))
-    pems = Pems(PemsConfig(v=V, k=1, P=P), lo, mesh=mesh)
-    store = pems.init()
-    tf, td = [], []
-    for _ in range({rounds}):
-        @jax.jit
-        def fused_call(data):
-            st = ContextStore(lo, data)
-            return pems.alltoallv(st, "send", "recv", mode="direct").data
-
-        @jax.jit
-        def dense_call(data):
-            st = ContextStore(lo, data)
-            return pems.alltoallv(st, "send", "recv", mode="direct",
-                                  use_kernel=False).data
-
-        data = jnp.array(store.data)
-        jax.block_until_ready(fused_call(data))
-        jax.block_until_ready(dense_call(data))
-        for _ in range({iters}):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fused_call(data))
-            tf.append(time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            jax.block_until_ready(dense_call(data))
-            td.append(time.perf_counter() - t0)
-    ratios = sorted(d / f for f, d in zip(tf, td))
-    tf.sort()
-    td.sort()
-    # Ledger figures from a fresh executor and exactly one call — the
-    # timing pems above accrues events at every retrace of both modes.
-    led = Pems(PemsConfig(v=V, k=1, P=P), lo, mesh=mesh)
-    led.alltoallv(led.init(), "send", "recv", mode="direct")
-    rows.append({{
-        "v": V,
-        "P": P,
-        "omega": omega,
-        "n_words": n_words,
-        "direct_us": round(tf[len(tf) // 2] * 1e6, 1),
-        "direct_min_us": round(tf[0] * 1e6, 1),
-        "direct_dense_us": round(td[len(td) // 2] * 1e6, 1),
-        "direct_dense_min_us": round(td[0] * 1e6, 1),
-        "speedup_vs_dense": round(ratios[len(ratios) // 2], 3),
-        "speedup_vs_dense_of_medians": round(td[len(td) // 2] / tf[len(tf) // 2], 3),
-        "speedup_vs_dense_min": round(td[0] / tf[0], 3),
-        "io_bytes_direct_k1": led.ledger.io_total,
-        "network_bytes": led.ledger.network,
-    }})
-print("P2JSON:" + json.dumps(rows))
-"""
-
-
 def _run_p2(sizes, iters, rounds):
-    """Run the P=2 mesh sweep in a subprocess (fake CPU devices) and return
-    its config rows.  Degrades to an empty list with a notice if the
-    subprocess fails — the P=1 sweep is the primary deliverable."""
-    script = _P2_SCRIPT.format(v=V, sizes=tuple(sizes), iters=iters,
-                               rounds=rounds)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = (
-        os.path.join(REPO_ROOT, "src")
-        + os.pathsep + env.get("PYTHONPATH", "")
-    )
-    try:
-        r = subprocess.run(
-            [sys.executable, "-c", script],
-            capture_output=True, text=True, timeout=1800, env=env,
-            cwd=REPO_ROOT,
-        )
-    except (subprocess.TimeoutExpired, OSError) as e:
-        print(f"# P=2 sweep failed, skipping: {e}", file=sys.stderr)
+    """The P=2 mesh sweep, in this process, over the first two devices.
+
+    With fewer than two devices it is skipped with a notice: devices are
+    never faked here, since a second process could not share an
+    accelerator this one already holds.  On a CPU host, start the
+    benchmark with ``XLA_FLAGS=--xla_force_host_platform_device_count=2``
+    for two host devices."""
+    devices = jax.devices()
+    if len(devices) < 2:
+        print(f"# P=2 sweep skipped: {len(devices)} {devices[0].platform} "
+              "device(s), it needs 2", file=sys.stderr)
         return []
-    for line in r.stdout.splitlines():
-        if line.startswith("P2JSON:"):
-            return json.loads(line[len("P2JSON:"):])
-    print(f"# P=2 sweep failed, skipping: {r.stderr[-500:]}", file=sys.stderr)
-    return []
+    P = 2
+    mesh = make_mesh_auto((P,), ("vp",), devices=devices[:P])
+    rows = []
+    for n_words in sizes:
+        omega = n_words // (V * V)
+        lo = (ContextLayout()
+              .add("send", (V, omega), jnp.int32)
+              .add("recv", (V, omega), jnp.int32))
+        pems = Pems(PemsConfig(v=V, k=1, P=P), lo, mesh=mesh)
+        store = pems.init()
+        tf, td = [], []
+        for _ in range(rounds):
+            @jax.jit
+            def fused_call(data):
+                st = ContextStore(lo, data)
+                return pems.alltoallv(st, "send", "recv", mode="direct").data
+
+            @jax.jit
+            def dense_call(data):
+                st = ContextStore(lo, data)
+                return pems.alltoallv(st, "send", "recv", mode="direct",
+                                      use_kernel=False).data
+
+            data = jnp.array(store.data)
+            f, d = _interleaved_times(fused_call, dense_call, data, iters)
+            tf.extend(f)
+            td.extend(d)
+        ratios = sorted(d / f for f, d in zip(tf, td))
+        tf.sort()
+        td.sort()
+        # Ledger figures from a fresh executor and exactly one call — the
+        # timing pems above accrues events at every retrace of both modes.
+        led = Pems(PemsConfig(v=V, k=1, P=P), lo, mesh=mesh)
+        led.alltoallv(led.init(), "send", "recv", mode="direct")
+        rows.append({
+            "v": V,
+            "P": P,
+            "omega": omega,
+            "n_words": n_words,
+            "direct_us": round(tf[len(tf) // 2] * 1e6, 1),
+            "direct_min_us": round(tf[0] * 1e6, 1),
+            "direct_dense_us": round(td[len(td) // 2] * 1e6, 1),
+            "direct_dense_min_us": round(td[0] * 1e6, 1),
+            "speedup_vs_dense": round(ratios[len(ratios) // 2], 3),
+            "speedup_vs_dense_of_medians": round(
+                td[len(td) // 2] / tf[len(tf) // 2], 3),
+            "speedup_vs_dense_min": round(td[0] / tf[0], 3),
+            "io_bytes_direct_k1": led.ledger.io_total,
+            "network_bytes": led.ledger.network,
+        })
+    return rows
 
 
 def _interleaved_times(fused_fn, dense_fn, data, iters):
@@ -253,7 +226,7 @@ def run(smoke: bool | None = None) -> None:
                 row[f"io_bytes_{mode}_k{k}"] = io
         configs.append(row)
 
-    # P = 2 mesh sweep (fused assembly route vs dense staging), subprocess.
+    # P = 2 mesh sweep (fused assembly route vs dense staging).
     p2_sizes = sizes[:2] if smoke else sizes
     p2_iters = 6 if smoke else 40
     p2_rounds = 1 if smoke else 3
@@ -265,13 +238,15 @@ def run(smoke: bool | None = None) -> None:
     out = {
         "benchmark": "alltoallv_direct_delivery",
         "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
+        "device_count": len(jax.devices()),
         "v": V,
         "smoke": bool(smoke),
         "note": ("direct_us is the fused word-level kernel path; "
                  "direct_dense_us is the seed dense-transpose implementation "
                  "measured with the identical protocol, interleaved in the "
-                 "same process; P=2 rows run the mesh network phase on two "
-                 "fake CPU devices in a subprocess"),
+                 "same process; P=2 rows run the mesh network phase on the "
+                 "first two devices"),
         "configs": configs,
     }
     # Smoke runs write to a separate file so CI / BENCH_FAST sweeps never

@@ -13,7 +13,11 @@ from typing import Callable
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.obs import Tracer
+
+# Every benchmark imports this module: one persistent compile cache for all.
+enable_compile_cache()
 
 # Shared process-wide tracer for every bench script's timed regions.
 TRACER = Tracer(name="bench")

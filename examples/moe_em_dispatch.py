@@ -16,9 +16,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models.blocks import moe_apply, moe_apply_dense_oracle, moe_params
 
+enable_compile_cache()
 cfg = get_config("kimi-k2-1t-a32b").smoke()
 print(f"MoE: {cfg.n_experts} experts, top-{cfg.top_k}, "
       f"capacity_factor={cfg.capacity_factor}")

@@ -10,7 +10,10 @@ byte of simulated external-memory traffic.
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.pems_apps import psrs_sort
+
+enable_compile_cache()
 
 n = 1 << 20
 rng = np.random.default_rng(0)

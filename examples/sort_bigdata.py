@@ -37,6 +37,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.pems_apps import psrs_run_recoverable, psrs_sort
 
 ap = argparse.ArgumentParser()
@@ -56,6 +57,29 @@ v, k = 16, 1   # k=1: the async tier keeps 3·k·mu in flight, capped below
 rng = np.random.default_rng(1)
 data = rng.integers(-2**31, 2**31 - 1, size=n, dtype=np.int32)
 want = np.sort(data)
+enable_compile_cache()
+
+# The kill -9 leg's child runs first, before this process touches a device:
+# an accelerator belongs to one process at a time, so a child started by a
+# parent that already holds it could not get it.
+crash_dir = crash_exit = None
+if args.inject_faults:
+    crash_dir = tempfile.TemporaryDirectory()
+    child = textwrap.dedent(f"""
+        import sys
+        import numpy as np
+        from repro.pems_apps import psrs_run_recoverable
+        rng = np.random.default_rng(1)
+        data = rng.integers(-2**31, 2**31 - 1, size={n}, dtype=np.int32)
+        psrs_run_recoverable(data, v={v}, k=2, state_dir=sys.argv[1],
+                             io_driver="buffered",
+                             crash_in_stage="merge")
+    """)
+    r = subprocess.run(
+        [sys.executable, "-c", child, os.path.join(crash_dir.name, "state")],
+        capture_output=True, text=True, timeout=600)
+    assert r.returncode == -signal.SIGKILL, (r.returncode, r.stderr[-2000:])
+    crash_exit = r.returncode
 
 # All-in-memory reference (the seed path, tier="device").
 t0 = time.perf_counter()
@@ -142,23 +166,10 @@ if args.inject_faults:
               f"{ts.retries} backoff={ts.backoff_s * 1e3:.1f}ms "
               f"permanent_errors={ts.permanent_errors}")
 
-        # kill -9 mid-stage, then resume from the durable superstep cursor.
-        state = os.path.join(td, "state")
-        child = textwrap.dedent(f"""
-            import sys
-            import numpy as np
-            from repro.pems_apps import psrs_run_recoverable
-            rng = np.random.default_rng(1)
-            data = rng.integers(-2**31, 2**31 - 1, size={n}, dtype=np.int32)
-            psrs_run_recoverable(data, v={v}, k=2, state_dir=sys.argv[1],
-                                 io_driver="buffered",
-                                 crash_in_stage="merge")
-        """)
-        r = subprocess.run([sys.executable, "-c", child, state],
-                           capture_output=True, text=True, timeout=600)
-        assert r.returncode == -signal.SIGKILL, (r.returncode,
-                                                 r.stderr[-2000:])
-        print(f"  child killed -9 mid-'merge' (exit {r.returncode}); "
+        # Resume the child killed -9 mid-stage at start-up from its durable
+        # superstep cursor.
+        state = os.path.join(crash_dir.name, "state")
+        print(f"  child killed -9 mid-'merge' (exit {crash_exit}); "
               "cursor + checksummed backing left behind — resuming ...")
         t0 = time.perf_counter()
         out2 = psrs_run_recoverable(data, v=v, k=2, state_dir=state,
@@ -167,6 +178,7 @@ if args.inject_faults:
         print(f"  resumed from the superstep cursor in "
               f"{time.perf_counter() - t0:.2f}s; output bit-identical to "
               "the uninterrupted run")
+    crash_dir.cleanup()
 
 print("\nPEMS2 direct vs PEMS1 indirect delivery (same sort, device tier):")
 for mode in ("direct", "indirect"):

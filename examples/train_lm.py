@@ -6,7 +6,10 @@ hundred steps on CPU with checkpointing, then resume to show crash recovery.
 
 import tempfile
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.train import main
+
+enable_compile_cache()
 
 with tempfile.TemporaryDirectory() as d:
     print("=== training 200 steps ===")

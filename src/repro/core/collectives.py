@@ -269,9 +269,6 @@ def _alltoallv_fused_mesh(self, store, send, recv, send_counts, recv_counts,
     """
     from repro.kernels.alltoallv_deliver import assemble_proc_fused
 
-    from .executor import _shard_map
-    shard_map = _shard_map()
-
     cfg = self.cfg
     lo = store.layout
     v, Pn, m, k = cfg.v, cfg.P, cfg.v_local, cfg.k
@@ -365,7 +362,7 @@ def _alltoallv_fused_mesh(self, store, send, recv, send_counts, recv_counts,
                         )
         return local
 
-    data = shard_map(
+    data = jax.shard_map(
         f,
         mesh=self.mesh,
         in_specs=(P(cfg.vp_axis, None),),
@@ -556,9 +553,6 @@ def _global_transpose(self, M: jnp.ndarray) -> jnp.ndarray:
     if cfg.P == 1:
         return jnp.swapaxes(M, 0, 1)
 
-    from .executor import _shard_map
-    shard_map = _shard_map()
-
     m = cfg.v_local
     Pn = cfg.P
     alpha = m if cfg.alpha is None else cfg.alpha
@@ -578,7 +572,7 @@ def _global_transpose(self, M: jnp.ndarray) -> jnp.ndarray:
         y = y.reshape(Pn * m, m, w)            # (src_global, dst_local, w)
         return jnp.swapaxes(y, 0, 1)           # (dst_local, src_global, w)
 
-    return shard_map(
+    return jax.shard_map(
         f,
         mesh=self.mesh,
         in_specs=(P(cfg.vp_axis, None, None),),
@@ -657,11 +651,12 @@ def bcast(self, store: ContextStore, field: str, root: int = 0,
             if store.on_disk:
                 self._account_disk(p * m, (p + 1) * m, row.nbytes,
                                    write=True)
-    else:
+    elif cfg.P == 1:
         vals = store.field(field)              # [v, ...]
-        val = lax.dynamic_index_in_dim(vals, root, axis=0, keepdims=False)
-        out = jnp.broadcast_to(val, vals.shape)
-        store = store.with_field(field, out)
+        val = lax.index_in_dim(vals, root, axis=0, keepdims=False)
+        store = store.with_field(field, jnp.broadcast_to(val, vals.shape))
+    else:
+        store = _bcast_mesh(self, store, field, root)
 
     B = cfg.block_bytes
     mu = self.layout.live_bytes
@@ -675,6 +670,33 @@ def bcast(self, store: ContextStore, field: str, root: int = 0,
         self.ledger.add_network((cfg.P - 1) * omega_b)
     self.ledger.add_barrier()
     return store
+
+
+def _bcast_mesh(self, store: ContextStore, field: str, root: int
+                ) -> ContextStore:
+    """Device-tier Bcast over the ``vp`` mesh: the root's owner reads its
+    field words locally, an ``all_gather`` of that one row crosses the
+    network, and every process writes it into its own rows.  No slice ever
+    indexes the sharded axis, so the store keeps its ``vp`` sharding."""
+    cfg = self.cfg
+    m = cfg.v_local
+    off = store.layout.offset(field)
+    nw = store.layout.field_words(field)
+
+    def f(local):                              # [m, words]: this proc's rows
+        row = lax.slice(local, (root % m, off), (root % m + 1, off + nw))
+        rows = lax.all_gather(row, cfg.vp_axis)        # [P, 1, nw]
+        val = rows[root // m]                          # [1, nw]
+        return lax.dynamic_update_slice(
+            local, jnp.broadcast_to(val, (m, nw)), (0, off))
+
+    data = jax.shard_map(
+        f,
+        mesh=self.mesh,
+        in_specs=(P(cfg.vp_axis, None),),
+        out_specs=P(cfg.vp_axis, None),
+    )(store.data)
+    return ContextStore(store.layout, data)
 
 
 def gather(self, store: ContextStore, send: str, recv: str, root: int = 0,

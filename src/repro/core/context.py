@@ -22,6 +22,7 @@ from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 WORD = 4  # bytes per store word
 
@@ -238,6 +239,20 @@ def layout(fields: Iterable[Tuple[str, Sequence[int], object]],
     return lo
 
 
+def row_major(x):
+    """Pin every array of the pytree ``x`` to the row-major layout.
+
+    XLA picks the physical layout of every value in a jitted program.  On
+    TPU it may give a ``[v, words]`` store, or a ``[k, words]`` round block,
+    the transposed layout that a small consumer prefers (a few-word field
+    slice, a batched sort): the short axis then lands in the 128-wide lane
+    dimension and the whole array is copied 8-32x padded.  Pinning the
+    store, the round blocks and the field slices keeps every such copy on
+    the slice."""
+    return jax.tree.map(
+        lambda a: with_layout_constraint(a, Layout(tuple(range(a.ndim)))), x)
+
+
 # --------------------------------------------------------------------------- #
 # Context view                                                                 #
 # --------------------------------------------------------------------------- #
@@ -320,7 +335,7 @@ class ContextStore:
         / result extraction; not part of the simulated I/O)."""
         off = self.layout.offset(name)
         f = self.layout.field(name)
-        flat = self.data[:, off:off + f.words]
+        flat = row_major(self.data[:, off:off + f.words])
         return _from_words(flat, f.dtype).reshape((self.v,) + f.shape)
 
     def with_field(self, name: str, value: jnp.ndarray) -> "ContextStore":
@@ -342,7 +357,7 @@ class ContextStore:
         across all contexts — no bitcast, no reshape to the field shape."""
         off = self.layout.offset(name)
         n = self.layout.field_words(name)
-        return jax.lax.slice(self.data, (0, off), (self.v, off + n))
+        return row_major(jax.lax.slice(self.data, (0, off), (self.v, off + n)))
 
     def with_field_words(self, name: str, words: jnp.ndarray) -> "ContextStore":
         """Write a field's raw word range from a ``[v, field_words]`` uint32

@@ -61,20 +61,11 @@ from .context import (
     ContextStore,
     field_word_index,
     init_store,
+    row_major,
 )
 from .iostats import IOLedger, TierStats
 
 DRIVERS = ("explicit", "sliced", "async")
-
-
-def _shard_map():
-    """jax >= 0.8 exports shard_map at top level; older releases keep it in
-    jax.experimental."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    return shard_map
 
 
 @dataclasses.dataclass
@@ -452,14 +443,15 @@ class Pems:
         if tier != "device":
             return self._init_tiered(init_fn, tier,
                                      backing_path or self.cfg.backing_path)
-        store = init_store(self.layout, self.cfg.v, init_fn)
-        if self.mesh is not None:
-            spec = P(self.cfg.vp_axis, None)
-            store = ContextStore(
-                self.layout,
-                jax.device_put(store.data, NamedSharding(self.mesh, spec)),
-            )
-        return store
+        if self.mesh is None:
+            return init_store(self.layout, self.cfg.v, init_fn)
+        # Built straight into its vp shards: no device ever holds the whole
+        # population (at P = 4 it is up to four times one device's memory).
+        data = jax.jit(
+            lambda: init_store(self.layout, self.cfg.v, init_fn).data,
+            out_shardings=NamedSharding(self.mesh, P(self.cfg.vp_axis, None)),
+        )()
+        return ContextStore(self.layout, data)
 
     def _init_tiered(self, init_fn, tier: str,
                      backing_path: Optional[str]) -> TieredStore:
@@ -579,17 +571,20 @@ class Pems:
         if cfg.P == 1:
             data = self._run_rounds(store.data, body, dev=None)
         else:
-            shard_map = _shard_map()
-
             def per_device(local):
                 dev = lax.axis_index(cfg.vp_axis)
                 return self._run_rounds(local, body, dev=dev)
 
-            data = shard_map(
+            # check_vma=False: superstep bodies are application code written
+            # per context, unaware of the mesh; a loop carry they start from
+            # a constant (e.g. kway_merge's splitter search) would otherwise
+            # be refused for not varying over the vp axis.
+            data = jax.shard_map(
                 per_device,
                 mesh=self.mesh,
                 in_specs=(P(cfg.vp_axis, None),),
                 out_specs=P(cfg.vp_axis, None),
+                check_vma=False,
             )(store.data)
         return ContextStore(lo, data)
 
@@ -654,7 +649,8 @@ class Pems:
                         return out
                     return out.take(out_i)
 
-                return jax.vmap(one)(rhos, rw)
+                # Pinned row-major like the device tier's round blocks.
+                return row_major(jax.vmap(one)(rhos, row_major(rw)))
 
             try:
                 cache[fn] = body
@@ -791,16 +787,16 @@ class Pems:
             # round's swap-in before computing the current one so the copy
             # can overlap compute.
             def sbody(carry, r):
-                data, blk = carry  # blk: prefetched round r
+                data, blk = row_major(carry)  # blk: prefetched round r
                 nxt = lax.dynamic_slice_in_dim(
                     data, (r + 1) % rounds * cfg.k, cfg.k, axis=0
                 )
-                nxt = jax.lax.optimization_barrier(nxt)
-                out = body(base + r * cfg.k, blk)
+                nxt = jax.lax.optimization_barrier(row_major(nxt))
+                out = row_major(body(base + r * cfg.k, blk))
                 data = lax.dynamic_update_slice_in_dim(
                     data, out, r * cfg.k, axis=0
                 )
-                return (data, nxt), None
+                return row_major((data, nxt)), None
 
             first = lax.dynamic_slice_in_dim(local_data, 0, cfg.k, axis=0)
             (data, _), _ = lax.scan(
@@ -808,11 +804,15 @@ class Pems:
             )
             return data
 
+        # Every carried store and round block is pinned row-major (see
+        # ``row_major``): left free, XLA may carry the store transposed.
         def sbody(data, r):
-            blk = lax.dynamic_slice_in_dim(data, r * cfg.k, cfg.k, axis=0)
-            out = body(base + r * cfg.k, blk)
+            data = row_major(data)
+            blk = row_major(
+                lax.dynamic_slice_in_dim(data, r * cfg.k, cfg.k, axis=0))
+            out = row_major(body(base + r * cfg.k, blk))
             data = lax.dynamic_update_slice_in_dim(data, out, r * cfg.k, axis=0)
-            return data, None
+            return row_major(data), None
 
         data, _ = lax.scan(sbody, local_data, jnp.arange(rounds))
         return data

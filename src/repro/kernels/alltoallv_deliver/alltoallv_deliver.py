@@ -4,34 +4,35 @@ The PEMS2 insight: deliver each message's aligned body straight into the
 destination context and fix up the unaligned edges from a small cache.  On
 TPU the analogue of the disk block is the 128-lane tile: the kernel streams
 message tiles HBM→VMEM with a *permuted* ``BlockSpec`` index map (the
-source's (s, d) tile lands at the destination's (d, s) slot — the offset
+sources' messages to destination ``d`` land in ``d``'s slot — the offset
 table ``T`` baked into the index map), and the per-message valid length
 ``counts[s, d]`` is applied as a lane mask — the boundary-block fix-up,
 performed while the tile is resident instead of with a read-modify-write
 cycle.
 
-Grid: ``(dst, src, ω/ωt)`` — one grid step moves one 128-lane ω-tile of one
-message, so arbitrarily large messages stream through VMEM in block-sized
-pieces instead of requiring the full ω payload resident at once.  For the
-``P > 1`` mesh path the grid grows a real-processor axis
-(:func:`assemble_proc_tiles`): each α-chunk is staged into the
-communication buffer with a ``(dst_proc, dst_local, src_local, ω/ωt)`` grid
-whose output index map writes source j's tile at the slot ``all_to_all``
-ships straight to the destination process' context row — the same
-offset-table permutation, now spanning the ``(src_proc, dst_proc)`` tiling
-of Alg 7.1.3, applied at the sender so the received buffer lands in the
-destination rows verbatim.  Two optional fusions ride along (both
-variants):
+Grid: ``(dst, ω/ωt)``.  One grid step moves one ``(S, ωt)`` slab — the
+ω-tile of every source's message to one destination — read from the
+``[S, dst·ω]`` word view and written as the destination's ``[S, ωt]``
+block of the ``[dst, S, ω]`` output, so every block spans whole rows and
+whole 128-lane tiles, as the TPU's ``(8, 128)`` tiling requires.  Large
+messages stream through VMEM in slab-sized pieces.  The counts ride in
+SMEM (scalar prefetch) and the mask is built from them in registers.  A
+message width ω that is not a multiple of 128 is padded to one and the
+padding sliced off.  For the ``P > 1`` mesh path (:func:`assemble_proc_tiles`)
+the destinations are the ``(dst_proc, dst_local)`` pairs of an α-chunk:
+source j's tile for destination (p, d) lands at the slot ``all_to_all``
+ships straight to process p's context row d — the same offset-table
+permutation, now spanning the ``(src_proc, dst_proc)`` tiling of Alg
+7.1.3, applied at the sender so the received buffer lands in the
+destination rows verbatim.  Two optional extras (both variants):
 
 * ``fill`` — the boundary mask.  When given, lanes past ``counts[s, d]`` are
   overwritten with ``fill`` while the tile is in VMEM (the receiver then
   never needs its own mask pass).  When ``None`` the tile is copied verbatim
-  and the counts input is not even streamed.
+  and no counts are passed.
 * ``counts_payload`` — the counts matrix itself.  Alltoallv must also hand
-  every receiver the transposed counts; passing the raw counts words here
-  adds a second (1, 1)-block output ``ct[d, s] = counts_payload[s, d]`` to
-  the same ``pallas_call``, so the counts transpose costs no extra kernel
-  launch or HBM round-trip.
+  every receiver the transposed counts; ``ct[d, s] = counts_payload[s, d]``
+  is returned alongside (a ``[v, v]`` word transpose beside the kernel).
 
 Backend selection — compiled Pallas on TPU, the vectorised fallback on
 CPU/GPU, interpret mode for bit-exact kernel emulation in tests — lives in
@@ -47,33 +48,80 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE_TILE = 128  # TPU lane width: the on-chip analogue of the disk block
+# Upper bound on the words of one (S, ωt) slab: a few MiB of VMEM with the
+# input and output double-buffered, far below the scoped limit.
+_SLAB_WORDS = 1 << 17
 
 
-def _deliver_kernel(*refs, omega_tile: int, fill, masked: bool,
-                    with_counts: bool):
-    """One grid step: move one ω-tile of message (s → d), boundary-masked."""
-    refs = list(refs)
-    cnt_ref = refs.pop(0) if masked else None
-    cp_ref = refs.pop(0) if with_counts else None
-    msg_ref = refs.pop(0)
-    out_ref = refs.pop(0)
-    ct_ref = refs.pop(0) if with_counts else None
-
-    data = msg_ref[0, 0, :]
+def _deliver_kernel(*refs, omega_tile: int, fill, masked: bool, S: int,
+                    E: int):
+    """One grid step: the ω-tile of every source's message to destination
+    ``e``, boundary-masked."""
     if masked:
-        t = pl.program_id(2)
-        cnt = cnt_ref[0, 0]
+        cnt_ref, msg_ref, out_ref = refs
+    else:
+        msg_ref, out_ref = refs
+    data = msg_ref[...]                              # (S, ωt)
+    if masked:
+        e, t = pl.program_id(0), pl.program_id(1)
+        src = jax.lax.broadcasted_iota(jnp.int32, (S, 1), 0)
+        cnt = jnp.zeros((S, 1), jnp.int32)
+        for s in range(S):                           # counts[:, e] from SMEM
+            cnt = jnp.where(src == s, cnt_ref[s * E + e], cnt)
         lane = t * omega_tile + jax.lax.broadcasted_iota(
-            jnp.int32, (omega_tile,), 0
-        )
+            jnp.int32, data.shape, 1)
         data = jnp.where(lane < cnt, data, jnp.asarray(fill, data.dtype))
-    out_ref[0, 0, :] = data
-    if with_counts:
-        # Idempotent across the ω-tile axis: the (d, s) block is revisited by
-        # every t step with the same value, staying resident in VMEM.
-        ct_ref[0, 0] = cp_ref[0, 0]
+    out_ref[...] = data
+
+
+def _omega_tile(S: int, omega: int) -> int:
+    """The widest multiple of 128 lanes dividing ``omega`` (itself a
+    multiple of 128) whose ``(S, ωt)`` slab fits ``_SLAB_WORDS``."""
+    q = omega // LANE_TILE
+    best = 1
+    for d in range(1, q + 1):
+        if q % d == 0 and S * d * LANE_TILE <= _SLAB_WORDS:
+            best = d
+    return best * LANE_TILE
+
+
+def _transpose_tiles(msgs: jnp.ndarray, counts: Optional[jnp.ndarray], *,
+                     fill, interpret: bool) -> jnp.ndarray:
+    """``out[e, s] = msgs[s, e]`` for ``msgs [S, E, ω]``, lanes at or past
+    ``counts[s, e]`` set to ``fill`` when ``fill`` is given."""
+    S, E, omega = msgs.shape
+    masked = fill is not None
+    pad = -omega % LANE_TILE
+    if pad:
+        msgs = jnp.pad(msgs, ((0, 0), (0, 0), (0, pad)))
+    wp = omega + pad
+    wt = _omega_tile(S, wp)
+    nt = wp // wt
+    kernel = functools.partial(_deliver_kernel, omega_tile=wt, fill=fill,
+                               masked=masked, S=S, E=E)
+    # The scalar-prefetch operand (counts) is passed to every index map
+    # after the grid indices; the maps ignore it.
+    in_spec = pl.BlockSpec((S, wt), lambda e, t, *_: (0, e * nt + t))
+    out_spec = pl.BlockSpec((None, S, wt), lambda e, t, *_: (e, 0, t))
+    args = [msgs.reshape(S, E * wp)]
+    if masked:
+        args.insert(0, counts.astype(jnp.int32).reshape(S * E))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=int(masked), grid=(E, nt),
+            in_specs=[in_spec], out_specs=out_spec),
+        # vma: inside shard_map the output varies over the same mesh axes
+        # as the messages.
+        out_shape=jax.ShapeDtypeStruct((E, S, wp), msgs.dtype,
+                                       vma=jax.typeof(msgs).vma),
+        interpret=interpret,
+        name="alltoallv_deliver",
+    )(*args)
+    return out[..., :omega] if pad else out
 
 
 def deliver_tiles(
@@ -82,82 +130,19 @@ def deliver_tiles(
     counts_payload: Optional[jnp.ndarray] = None,  # [v, v] raw counts words
     *,
     fill=None,
-    omega_tile: int = LANE_TILE,
     interpret: bool = False,
 ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """Returns ``(out, ct)`` with ``out[d, s] = msgs[s, d]`` (lanes ≥
     ``counts[s, d]`` replaced by ``fill`` when ``fill`` is not ``None``) and
     ``ct[d, s] = counts_payload[s, d]`` (``None`` when no payload given)."""
-    v, v2, omega = msgs.shape
+    v, v2, _ = msgs.shape
     assert v == v2, msgs.shape
-    masked = fill is not None
-    if masked and counts is None:
+    if fill is not None and counts is None:
         raise ValueError("fill requires counts")
-    with_counts = counts_payload is not None
-
-    wt = min(omega_tile, omega)
-    nt = -(-omega // wt)                     # ceil: last tile may be ragged
-    kernel = functools.partial(
-        _deliver_kernel, omega_tile=wt, fill=fill, masked=masked,
-        with_counts=with_counts,
-    )
-
-    in_specs, args = [], []
-    if masked:
-        in_specs.append(pl.BlockSpec((1, 1), lambda d, s, t: (s, d)))
-        args.append(counts)
-    if with_counts:
-        in_specs.append(pl.BlockSpec((1, 1), lambda d, s, t: (s, d)))
-        args.append(counts_payload)
-    in_specs.append(pl.BlockSpec((1, 1, wt), lambda d, s, t: (s, d, t)))
-    args.append(msgs)
-
-    out_specs = [pl.BlockSpec((1, 1, wt), lambda d, s, t: (d, s, t))]
-    out_shape = [jax.ShapeDtypeStruct((v, v, omega), msgs.dtype)]
-    if with_counts:
-        out_specs.append(pl.BlockSpec((1, 1), lambda d, s, t: (d, s)))
-        out_shape.append(
-            jax.ShapeDtypeStruct((v, v), counts_payload.dtype)
-        )
-
-    out = pl.pallas_call(
-        kernel,
-        grid=(v, v, nt),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*args)
-    if with_counts:
-        return out[0], out[1]
-    return out[0], None
-
-
-
-def _assemble_proc_kernel(*refs, omega_tile: int, fill, masked: bool,
-                          with_counts: bool):
-    """One grid step of the mesh variant: stage one ω-tile of the message
-    (src_local j → dst_proc p, dst_local d) into the communication buffer,
-    boundary-masked at the source."""
-    refs = list(refs)
-    cnt_ref = refs.pop(0) if masked else None
-    cp_ref = refs.pop(0) if with_counts else None
-    msg_ref = refs.pop(0)
-    out_ref = refs.pop(0)
-    ct_ref = refs.pop(0) if with_counts else None
-
-    data = msg_ref[0, 0, 0, :]
-    if masked:
-        t = pl.program_id(3)
-        cnt = cnt_ref[0, 0, 0]
-        lane = t * omega_tile + jax.lax.broadcasted_iota(
-            jnp.int32, (omega_tile,), 0
-        )
-        data = jnp.where(lane < cnt, data, jnp.asarray(fill, data.dtype))
-    out_ref[0, 0, 0, :] = data
-    if with_counts:
-        # Revisited with the same value by every ω-tile step (idempotent).
-        ct_ref[0, 0, 0] = cp_ref[0, 0, 0]
+    out = _transpose_tiles(msgs, counts, fill=fill, interpret=interpret)
+    ct = (None if counts_payload is None
+          else jnp.swapaxes(counts_payload, 0, 1))
+    return out, ct
 
 
 def assemble_proc_tiles(
@@ -166,7 +151,6 @@ def assemble_proc_tiles(
     counts_payload: Optional[jnp.ndarray] = None,  # [s, P, d] raw counts words
     *,
     fill=None,
-    omega_tile: int = LANE_TILE,
     interpret: bool = False,
 ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """The ``(src_proc, dst_proc)``-tiled grid of the ``P > 1`` mesh path:
@@ -185,53 +169,12 @@ def assemble_proc_tiles(
     payload given): the transposed counts ride along to the same receiver.
     """
     s, Pn, d, omega = msgs.shape
-    masked = fill is not None
-    if masked and counts is None:
+    if fill is not None and counts is None:
         raise ValueError("fill requires counts")
-    with_counts = counts_payload is not None
-
-    wt = min(omega_tile, omega)
-    nt = -(-omega // wt)                     # ceil: last tile may be ragged
-    kernel = functools.partial(
-        _assemble_proc_kernel, omega_tile=wt, fill=fill, masked=masked,
-        with_counts=with_counts,
-    )
-
-    in_specs, args = [], []
-    if masked:
-        in_specs.append(pl.BlockSpec((1, 1, 1), lambda p, d, j, t: (j, p, d)))
-        args.append(counts)
-    if with_counts:
-        in_specs.append(pl.BlockSpec((1, 1, 1), lambda p, d, j, t: (j, p, d)))
-        args.append(counts_payload)
-    in_specs.append(
-        pl.BlockSpec((1, 1, 1, wt), lambda p, d, j, t: (j, p, d, t))
-    )
-    args.append(msgs)
-
-    # The (p, d) output tiling is the offset table T spanning the process
-    # grid: source j's tile for destination (p, d) lands at the slot the
-    # all_to_all ships straight to process p's context row d.
-    out_specs = [
-        pl.BlockSpec((1, 1, 1, wt), lambda p, d, j, t: (p, d, j, t))
-    ]
-    out_shape = [jax.ShapeDtypeStruct((Pn, d, s, omega), msgs.dtype)]
-    if with_counts:
-        out_specs.append(
-            pl.BlockSpec((1, 1, 1), lambda p, d, j, t: (p, d, j))
-        )
-        out_shape.append(
-            jax.ShapeDtypeStruct((Pn, d, s), counts_payload.dtype)
-        )
-
-    out = pl.pallas_call(
-        kernel,
-        grid=(Pn, d, s, nt),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*args)
-    if with_counts:
-        return out[0], out[1]
-    return out[0], None
+    out = _transpose_tiles(
+        msgs.reshape(s, Pn * d, omega),
+        None if counts is None else counts.reshape(s, Pn * d),
+        fill=fill, interpret=interpret)
+    ct = (None if counts_payload is None
+          else jnp.moveaxis(counts_payload, 0, 2))
+    return out.reshape(Pn, d, s, omega), ct

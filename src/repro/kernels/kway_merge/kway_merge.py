@@ -10,67 +10,38 @@ size, not ``v×`` it).  Grid steps therefore merge disjoint output ranges
 and never communicate; what is left per tile is ordering its ``tile``
 elements.
 
-That ordering is a bitonic sorting network over the row — the same
-gather-free ``reshape`` + ``min``/``max``/``where`` idiom as the
-``bitonic_sort`` kernel, ``log²(tile)`` unrolled vector steps, one grid
-step per tile entirely inside VMEM.  Per output element the work is
-``log²(tile)/2`` branchless vector ops — *constant in both n and v* — so
-across the grid the merge costs O(n·log² tile), versus the O(n log n)
-comparator re-sort of all ``v·cap`` received lanes it replaces (which also
-paid to re-discover the order the buckets already had).
+That ordering is the bitonic sorting network of
+:mod:`repro.kernels.bitonic_sort` (one definition, lane and sublane rolls
+plus selects), ``log²(tile)/2`` unrolled vector steps per tile, entirely
+inside VMEM.  Per output element the work is ``log²(tile)/2`` branchless
+vector ops — *constant in both n and v* — so across the grid the merge
+costs O(n·log² tile), versus the O(n log n) comparator re-sort of all
+``v·cap`` received lanes it replaces (which also paid to re-discover the
+order the buckets already had).
 
-``merge_tile_grid`` is the Pallas grid; ``sort_tile_rows`` is the same
+``merge_tile_grid`` is the Pallas grid (the bitonic kernel, named
+``kway_merge`` in the compiled program); ``sort_tile_rows`` is the same
 network as one batched jnp expression (the CPU/GPU fallback — both produce
-the unique ascending permutation of each row, so they are bit-identical
-by construction).
+the unique ascending permutation of each row, so they are bit-identical by
+construction).
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from repro.kernels.bitonic_sort.bitonic_sort import (bitonic_network,
+                                                     bitonic_sort_rows)
 
 
 def sort_tile_rows(x: jnp.ndarray) -> jnp.ndarray:
     """Ascending bitonic sort of the last axis of ``[..., t]``; ``t`` must
-    be a power of two.  Pure jnp — the kernel body runs it on one tile row,
-    the CPU/GPU fallback on the whole ``[G, tile]`` batch at once."""
-    *lead, t = x.shape
-    assert t & (t - 1) == 0, f"tile={t} must be a power of two"
-    log_t = t.bit_length() - 1
-    for stage in range(log_t):
-        for sub in range(stage, -1, -1):
-            stride = 1 << sub
-            groups = t // (2 * stride)
-            xr = x.reshape(*lead, groups, 2, stride)
-            a, b = xr[..., 0, :], xr[..., 1, :]
-            # Ascending iff bit (stage+1) of the element index is 0 —
-            # constant within a group, alternating with period
-            # 2^(stage-sub) in group index (bitonic_sort's direction rule).
-            g = jax.lax.broadcasted_iota(jnp.int32, (groups, 1), 0)
-            asc = ((g >> (stage - sub)) & 1) == 0
-            lo = jnp.minimum(a, b)
-            hi = jnp.maximum(a, b)
-            na = jnp.where(asc, lo, hi)
-            nb = jnp.where(asc, hi, lo)
-            x = jnp.stack([na, nb], axis=-2).reshape(*lead, t)
-    return x
-
-
-def _kway_merge_kernel(tiles_ref, o_ref):
-    o_ref[0, :] = sort_tile_rows(tiles_ref[0, :])
+    be a power of two.  Pure jnp — the CPU/GPU fallback runs it on the whole
+    ``[G, tile]`` batch at once."""
+    return bitonic_network(x, x.shape[-1])
 
 
 def merge_tile_grid(tiles: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
-    """Order each compactly-gathered output tile of ``tiles [G, tile]``;
-    one grid step per tile, each entirely in VMEM."""
-    G, tile = tiles.shape
-    return pl.pallas_call(
-        _kway_merge_kernel,
-        grid=(G,),
-        in_specs=[pl.BlockSpec((1, tile), lambda g: (g, 0))],
-        out_specs=pl.BlockSpec((1, tile), lambda g: (g, 0)),
-        out_shape=jax.ShapeDtypeStruct((G, tile), tiles.dtype),
-        interpret=interpret,
-    )(tiles)
+    """Order each compactly-gathered output tile of ``tiles [G, tile]``
+    with the bitonic kernel, entirely in VMEM."""
+    return bitonic_sort_rows(tiles, interpret=interpret, name="kway_merge")

@@ -25,8 +25,10 @@ Pipeline:
    its tile's elements.  No per-bucket padding: gather traffic equals
    output size.
 4. **Tile merge** — a bitonic sorting network over each row, as the Pallas
-   grid (one step per tile) or one batched jnp expression, backend
-   dispatched like every other kernel here.
+   grid or one batched jnp expression, backend dispatched like every other
+   kernel here; a tile wider than the bitonic kernel's
+   ``KERNEL_MAX_N`` takes ``jnp.sort`` (the same size rule as the local
+   sort).
 
 Backend selection follows :func:`repro.kernels.alltoallv_deliver.ops.uses_pallas`:
 ``interpret=None`` (default) compiles the Pallas kernel on TPU and takes
@@ -49,40 +51,18 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.alltoallv_deliver.ops import uses_pallas
+from repro.kernels.bitonic_sort.ops import KERNEL_MAX_N
 
 from .kway_merge import merge_tile_grid, sort_tile_rows
 
 _SUPPORTED = ("int32", "uint32")
 
 
-def _register_barrier_batching() -> None:
-    """``lax.optimization_barrier`` has no vmap batching rule in the pinned
-    jax; the barrier is shape-preserving and batch-oblivious, so the rule
-    is the identity on batch dims.  Registered once, guarded so a future
-    jax that ships its own rule wins."""
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-        if optimization_barrier_p not in batching.primitive_batchers:
-            def _rule(args, dims, **params):
-                return optimization_barrier_p.bind(*args, **params), dims
-            batching.primitive_batchers[optimization_barrier_p] = _rule
-    except ImportError:            # pragma: no cover - jax internals moved
-        pass
-
-
-_register_barrier_batching()
-
-
 def _materialize(x: jnp.ndarray) -> jnp.ndarray:
     """Fusion barrier: force ``x`` into memory once instead of letting XLA
     re-fuse its producer chain into every consumer (the window gather
-    otherwise re-runs inside each tournament stage — measured ~1.5x on the
-    whole op on CPU)."""
-    try:
-        return jax.lax.optimization_barrier(x)
-    except NotImplementedError:    # pragma: no cover - missing batching rule
-        return x
+    otherwise re-runs inside each tournament stage)."""
+    return jax.lax.optimization_barrier(x)
 
 
 def _to_biased_u32(x: jnp.ndarray) -> jnp.ndarray:
@@ -214,8 +194,10 @@ def kway_merge(
     tiles = jnp.where(valid, jnp.take(masked.reshape(-1), flat), fill_v)
     tiles = _materialize(tiles)               # don't re-fuse into the network
 
-    if use_kernel and uses_pallas(interpret):
+    if use_kernel and uses_pallas(interpret) and tile <= KERNEL_MAX_N:
         merged = merge_tile_grid(tiles, interpret=bool(interpret))
-    else:
+    elif tile <= KERNEL_MAX_N:
         merged = sort_tile_rows(tiles)        # batched over the whole grid
+    else:                                     # the bitonic size rule
+        merged = jnp.sort(tiles, axis=-1)
     return merged.reshape(G * tile)[:rcap], total, overflow

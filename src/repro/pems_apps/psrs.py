@@ -206,7 +206,12 @@ def _build(v: int, k: int, n_v: int, cap, rcap, driver: str,
     steps = [(name, _staged(name, fn)) for name, fn in steps]
 
     def load(data_blocks):                  # [v, n_v] int32
-        return pems.init().with_field("data", data_blocks)
+        store = pems.init()
+        if pems.mesh is not None:
+            # Each device receives its own contexts' rows (and an explicit
+            # mesh requires the update to match the store's sharding).
+            data_blocks = jax.device_put(data_blocks, store.data.sharding)
+        return store.with_field("data", data_blocks)
 
     def extract(store):
         return (store.field("result"), store.field("rcount"),
@@ -336,7 +341,8 @@ def psrs_sort(
 
     ``P``/``mesh`` run the simulation over ``P`` real processors: each
     process owns ``v/P`` contexts.  On the device tier a jax mesh with the
-    ``vp`` axis is required and the final Alltoallv's network phase is
+    ``vp`` axis in Auto mode is required
+    (:func:`repro.launch.mesh.make_mesh_auto`) and the final Alltoallv's network phase is
     α-chunked over the mesh (``alpha``, Alg 7.1.3) — through the fused
     (src_proc, dst_proc)-tiled delivery kernel by default, bit-identical to
     the dense ``use_kernel=False`` route and to the ``P == 1`` reference.

@@ -117,7 +117,8 @@ _P2_PSRS = textwrap.dedent("""
     ref = psrs_sort(data, v=v, k=k)          # P == 1 seed reference
     np.testing.assert_array_equal(ref, np.sort(data))
 
-    mesh = jax.make_mesh((2,), ("vp",))
+    from repro.launch.mesh import make_mesh_auto
+    mesh = make_mesh_auto((2,), ("vp",))
     for driver in ("explicit", "sliced", "async"):
         for use_kernel in (True, False):
             out = psrs_sort(data, v=v, k=k, driver=driver, P=2, mesh=mesh,

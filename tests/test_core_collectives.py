@@ -403,6 +403,6 @@ def test_multiprocessor_alltoallv_subprocess():
              # Without an explicit platform, jax probes for TPUs via the
              # cloud metadata URL and stalls for minutes off-cloud.
              "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")},
-        cwd="/root/repo",
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     )
     assert "MULTIPROC_OK" in r.stdout, r.stderr[-3000:]
