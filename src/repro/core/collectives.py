@@ -134,16 +134,21 @@ def alltoallv(
     if isinstance(store, TieredStore):
         store = _alltoallv_host(self, store, send, recv,
                                 send_counts, recv_counts, fill, procs)
-    elif mode == "direct" and use_kernel:
-        if cfg.P == 1:
-            store = _alltoallv_fused(self, store, send, recv,
-                                     send_counts, recv_counts, fill)
-        else:
-            store = _alltoallv_fused_mesh(self, store, send, recv,
-                                          send_counts, recv_counts, fill)
     else:
-        store = _alltoallv_dense(self, store, send, recv,
-                                 send_counts, recv_counts, mode, fill)
+        # The device paths' operations carry the collective's name in the
+        # profiler's trace, in a jitted program as in an eager one.
+        with jax.named_scope("pems.alltoallv"):
+            if mode != "direct" or not use_kernel:
+                store = _alltoallv_dense(self, store, send, recv,
+                                         send_counts, recv_counts, mode,
+                                         fill)
+            elif cfg.P == 1:
+                store = _alltoallv_fused(self, store, send, recv,
+                                         send_counts, recv_counts, fill)
+            else:
+                store = _alltoallv_fused_mesh(self, store, send, recv,
+                                              send_counts, recv_counts,
+                                              fill)
 
     _ledger_alltoallv(self, omega_b, mode)
     return store
@@ -652,11 +657,14 @@ def bcast(self, store: ContextStore, field: str, root: int = 0,
                 self._account_disk(p * m, (p + 1) * m, row.nbytes,
                                    write=True)
     elif cfg.P == 1:
-        vals = store.field(field)              # [v, ...]
-        val = lax.index_in_dim(vals, root, axis=0, keepdims=False)
-        store = store.with_field(field, jnp.broadcast_to(val, vals.shape))
+        with jax.named_scope("pems.bcast"):
+            vals = store.field(field)          # [v, ...]
+            val = lax.index_in_dim(vals, root, axis=0, keepdims=False)
+            store = store.with_field(field,
+                                     jnp.broadcast_to(val, vals.shape))
     else:
-        store = _bcast_mesh(self, store, field, root)
+        with jax.named_scope("pems.bcast"):
+            store = _bcast_mesh(self, store, field, root)
 
     B = cfg.block_bytes
     mu = self.layout.live_bytes
@@ -725,10 +733,11 @@ def gather(self, store: ContextStore, send: str, recv: str, root: int = 0,
             if store.on_disk:
                 self._account_disk(root, root + 1, w.nbytes, write=True)
     else:
-        A = store.field(send)                  # [v, ...] gathered result
-        R = store.field(recv)                  # [v, v, ...]
-        R = R.at[root].set(A.astype(fr.dtype))
-        store = store.with_field(recv, R)
+        with jax.named_scope("pems.gather"):
+            A = store.field(send)              # [v, ...] gathered result
+            R = store.field(recv)              # [v, v, ...]
+            R = R.at[root].set(A.astype(fr.dtype))
+            store = store.with_field(recv, R)
 
     B = cfg.block_bytes
     omega_b = self.layout.field_bytes(send)

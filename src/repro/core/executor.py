@@ -38,7 +38,6 @@ and ``Pems.tier_stats`` the wall-clock overlap.
 from __future__ import annotations
 
 import dataclasses
-import os
 import time
 import weakref
 from concurrent.futures import ThreadPoolExecutor
@@ -51,8 +50,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.io import IO_DRIVERS
-from repro.obs import NOOP, Tracer, merge_trace_files, trace_events, \
-    write_trace
+from repro.obs import NOOP, Tracer, trace_events, write_trace
 
 from .backing import TIERS, TieredStore, make_backing
 from .context import (
@@ -120,8 +118,7 @@ class PemsConfig:
       superstep/round/engine/collective/recovery spans into per-process
       ring buffers (results stay bit-identical; hot paths pay one
       attribute check when off).  ``trace_path`` is where
-      :meth:`Pems.export_trace` writes the merged Perfetto JSON (and the
-      per-process ``<path>.p<p>`` part files under a sharded backing).
+      :meth:`Pems.export_trace` writes the merged Perfetto JSON.
 
     Raises ``ValueError`` at construction for any invalid combination —
     unknown names, out-of-range ``alpha``, ``io_*`` knobs without
@@ -383,12 +380,13 @@ class Pems:
     def export_trace(self, path: Optional[str] = None) -> str:
         """Write the recorded spans as one Perfetto-loadable JSON trace.
 
-        Under a sharded backing each per-process tracer is first written to
-        its own ``<path>.p<p>`` part file, then the parts are merged (each
-        keeping its own process lane) with the main tracer's events and the
-        :meth:`metrics_snapshot` into ``path`` (default: the config's
-        ``trace_path``).  Load the result in https://ui.perfetto.dev or
-        summarize it with ``python -m repro.obs report <path>``."""
+        The main tracer's events (pid 0) and, under a backing tier, each
+        per-process tracer's (pid ``p+1``) are merged in memory, each
+        keeping its own process lane, and written with the
+        :meth:`metrics_snapshot` and the shared epoch's clock readings to
+        ``path`` (default: the config's ``trace_path``).  Load the result
+        in https://ui.perfetto.dev or summarize it with
+        ``python -m repro.obs report <path>``."""
         path = self.cfg.trace_path if path is None else path
         if path is None:
             raise ValueError(
@@ -398,22 +396,13 @@ class Pems:
             raise ValueError(
                 "export_trace requires PemsConfig(trace=True) — nothing "
                 "recorded spans")
-        parts = []
+        events = trace_events(self.tracer, pid=0, process_name="main")
         if self.shard_tracers[0] is not self.tracer:
             for p, tr in enumerate(self.shard_tracers):
-                pp = f"{path}.p{p}"
-                write_trace(pp, trace_events(tr, pid=p + 1,
-                                             process_name=tr.name))
-                parts.append(pp)
-        main_events = trace_events(self.tracer, pid=0, process_name="main")
-        out = merge_trace_files(path, parts, extra_events=main_events,
-                                metrics=self.metrics_snapshot())
-        for pp in parts:                     # merged: the parts are spent
-            try:
-                os.unlink(pp)
-            except OSError:
-                pass
-        return out
+                events += trace_events(tr, pid=p + 1, process_name=tr.name)
+        events.sort(key=lambda e: (e["ph"] != "M", e.get("ts", 0.0)))
+        return write_trace(path, events, metrics=self.metrics_snapshot(),
+                           tracer=self.tracer)
 
     def _account_disk(self, r0: int, r1: int, row_bytes: int,
                       write: bool) -> None:
@@ -542,6 +531,12 @@ class Pems:
         disjoint rows); ``TierStats.merge_prefetch_events`` counts the
         overlapped swap-ins and ``merge_stall_s`` the residual blocking.
         """
+        if (not isinstance(store, TieredStore)
+                and isinstance(store.data, jax.core.Tracer)):
+            # Inside a jitted program a span would time the trace, once;
+            # the stage's own named scope labels its device operations.
+            return self._superstep_impl(store, fn, reads, writes, procs,
+                                        stream)
         with self.tracer.span(f"superstep:{name}", tid="supersteps",
                               cat="superstep", driver=self.cfg.driver,
                               stream=stream):

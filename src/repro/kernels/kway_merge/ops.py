@@ -75,6 +75,7 @@ def _to_biased_u32(x: jnp.ndarray) -> jnp.ndarray:
     return x
 
 
+@jax.named_scope("kway_merge.splitters")
 def _exact_starts(rows_u32: jnp.ndarray, ranks: jnp.ndarray) -> jnp.ndarray:
     """Per-bucket window starts for global ``ranks`` over ``v`` ascending
     uint32 rows: ``starts[r, j]`` with ``Σ_j starts[r, j] = ranks[r]``.
@@ -173,6 +174,9 @@ def kway_merge(
     ranks = jnp.minimum(
         jnp.arange(G + 1, dtype=jnp.int32) * tile, jnp.int32(n_all))
 
+    # The three phases' operations carry their names in the profiler's
+    # trace: kway_merge.splitters (_exact_starts), kway_merge.gather and
+    # kway_merge.tiles.
     rows_u32 = _to_biased_u32(masked)
     starts = _exact_starts(rows_u32, ranks)   # [G+1, v]
 
@@ -181,23 +185,25 @@ def kway_merge(
     # concatenate into one dense [tile] row.  Slot s of tile g belongs to
     # the bucket whose exclusive length-prefix covers s; a searchsorted
     # over that prefix finds it without materialising [G, v, tile].
-    lens = starts[1:] - starts[:-1]                            # [G, v]
-    cum = jnp.cumsum(lens, axis=1) - lens                      # excl prefix
-    slot = jnp.arange(tile, dtype=jnp.int32)
-    own = jax.vmap(
-        lambda c: jnp.searchsorted(c, slot, side="right")
-    )(cum).astype(jnp.int32) - 1                               # [G, tile]
-    off = slot[None, :] - jnp.take_along_axis(cum, own, axis=1)
-    valid = off < jnp.take_along_axis(lens, own, axis=1)       # last tile only
-    pos = jnp.take_along_axis(starts[:-1], own, axis=1) + off
-    flat = own * cap + jnp.clip(pos, 0, cap - 1)
-    tiles = jnp.where(valid, jnp.take(masked.reshape(-1), flat), fill_v)
-    tiles = _materialize(tiles)               # don't re-fuse into the network
+    with jax.named_scope("kway_merge.gather"):
+        lens = starts[1:] - starts[:-1]                        # [G, v]
+        cum = jnp.cumsum(lens, axis=1) - lens                  # excl prefix
+        slot = jnp.arange(tile, dtype=jnp.int32)
+        own = jax.vmap(
+            lambda c: jnp.searchsorted(c, slot, side="right")
+        )(cum).astype(jnp.int32) - 1                           # [G, tile]
+        off = slot[None, :] - jnp.take_along_axis(cum, own, axis=1)
+        valid = off < jnp.take_along_axis(lens, own, axis=1)   # last tile
+        pos = jnp.take_along_axis(starts[:-1], own, axis=1) + off
+        flat = own * cap + jnp.clip(pos, 0, cap - 1)
+        tiles = jnp.where(valid, jnp.take(masked.reshape(-1), flat), fill_v)
+        tiles = _materialize(tiles)           # don't re-fuse into the network
 
-    if use_kernel and uses_pallas(interpret) and tile <= KERNEL_MAX_N:
-        merged = merge_tile_grid(tiles, interpret=bool(interpret))
-    elif tile <= KERNEL_MAX_N:
-        merged = sort_tile_rows(tiles)        # batched over the whole grid
-    else:                                     # the bitonic size rule
-        merged = jnp.sort(tiles, axis=-1)
+    with jax.named_scope("kway_merge.tiles"):
+        if use_kernel and uses_pallas(interpret) and tile <= KERNEL_MAX_N:
+            merged = merge_tile_grid(tiles, interpret=bool(interpret))
+        elif tile <= KERNEL_MAX_N:
+            merged = sort_tile_rows(tiles)    # batched over the whole grid
+        else:                                 # the bitonic size rule
+            merged = jnp.sort(tiles, axis=-1)
     return merged.reshape(G * tile)[:rcap], total, overflow

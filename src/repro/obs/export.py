@@ -1,15 +1,19 @@
-"""Chrome/Perfetto ``trace_event`` JSON export + per-process trace merge.
+"""Chrome/Perfetto ``trace_event`` JSON export.
 
 Produces the JSON *object* format (``{"traceEvents": [...], ...}``), which
 both ``chrome://tracing`` and https://ui.perfetto.dev load directly and
 which permits extra top-level keys — the flat metrics snapshot rides along
 under ``"metrics"`` so one file carries spans *and* the ``TierStats``/
-``IOLedger`` counters they must agree with.
+``IOLedger`` counters they must agree with, and the tracers' epoch under
+``"clock"`` as both a ``perf_counter`` and a ``time.time_ns()`` reading, so
+ring events can be placed on a ``jax.profiler`` trace's clock
+(``time_ns + ts``).
 
 Lane layout: each tracer becomes one Perfetto *process* (``pid``) — the
 executor's main tracer is pid 0, shard ``p``'s engine/round tracer pid
 ``p+1`` — and each distinct ``tid`` string inside a tracer becomes one
-named *thread* lane.  Timestamps are exported in microseconds as the
+named *thread* lane; the lanes are merged in memory and written once.
+Timestamps are exported in microseconds since the shared epoch, as the
 format requires.
 
 Balance sanitation: ``B``/``E`` events are matched per lane on export —
@@ -24,7 +28,7 @@ from __future__ import annotations
 import json
 from typing import Dict, Iterable, List, Optional
 
-__all__ = ["trace_events", "write_trace", "merge_trace_files", "load_trace"]
+__all__ = ["trace_events", "write_trace", "load_trace"]
 
 _US = 1e6
 
@@ -83,11 +87,16 @@ def trace_events(tracer, pid: int,
 
 
 def write_trace(path: str, events: Iterable[dict],
-                metrics: Optional[dict] = None) -> str:
-    """Write one Perfetto-loadable JSON object trace file."""
+                metrics: Optional[dict] = None, tracer=None) -> str:
+    """Write one Perfetto-loadable JSON object trace file.  ``tracer``
+    (any tracer on the events' shared epoch) adds its epoch under
+    ``"clock"``: ``perf_counter_s`` and the same instant as ``time_ns``."""
     doc = {"traceEvents": list(events), "displayTimeUnit": "ms"}
     if metrics is not None:
         doc["metrics"] = metrics
+    if tracer is not None:
+        doc["clock"] = {"perf_counter_s": tracer.epoch,
+                        "time_ns": tracer.epoch_time_ns}
     with open(path, "w") as f:
         json.dump(doc, f)
     return path
@@ -96,25 +105,3 @@ def write_trace(path: str, events: Iterable[dict],
 def load_trace(path: str) -> dict:
     with open(path) as f:
         return json.load(f)
-
-
-def merge_trace_files(path: str, part_paths: Iterable[str],
-                      extra_events: Iterable[dict] = (),
-                      metrics: Optional[dict] = None) -> str:
-    """Merge per-process trace files (plus ``extra_events``, e.g. the main
-    tracer's already-converted events) into one trace at ``path``.
-
-    Events keep their pids (each part file was exported under its own), so
-    the merged view shows one Perfetto process lane per source process;
-    part-file ``metrics`` dicts are folded under the part's process name.
-    """
-    events: List[dict] = list(extra_events)
-    merged_metrics: dict = dict(metrics or {})
-    for pp in part_paths:
-        doc = load_trace(pp)
-        events.extend(doc.get("traceEvents", ()))
-        for k, v in doc.get("metrics", {}).items():
-            merged_metrics.setdefault(k, v)
-    events.sort(key=lambda e: (e["ph"] != "M", e.get("ts", 0.0)))
-    return write_trace(path, events,
-                       metrics=merged_metrics if merged_metrics else None)

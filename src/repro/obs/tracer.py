@@ -15,7 +15,8 @@ Design constraints (and how they are met):
   locking on the hot path (CPython's deque append is atomic, which is all
   the single-producer-per-lane usage here needs).  When tracing is off the
   plumbing holds the :data:`NOOP` singleton, so instrumented code pays one
-  attribute check (``tracer.enabled``) or one no-op method call.
+  attribute check (``tracer.enabled``) or one no-op method call — and a
+  ``span()`` one profiler annotation (below).
 * **Bounded memory.**  The ring drops the *oldest* events past
   ``capacity`` (``dropped`` counts them) — a week-long run cannot OOM on
   its own telemetry.
@@ -23,14 +24,23 @@ Design constraints (and how they are met):
   a shared ``epoch``, immune to wall-clock steps.  Tracers that should
   share a timeline (the executor's per-shard tracers) are constructed with
   the same ``epoch`` so their events merge onto comparable timestamps.
+  ``epoch_time_ns`` is the same instant as a ``time.time_ns()`` reading,
+  which places the ring's events on a profiler trace's clock.
+* **One name on both clocks.**  Every :meth:`Tracer.span` — on
+  :data:`NOOP` too — also enters a ``jax.profiler.TraceAnnotation`` of
+  its name, so a ``jax.profiler`` session records the span in its host
+  plane beside the device's operations; without a session that is one
+  flag check.  :meth:`complete`, :meth:`instant` and :meth:`counter` go
+  to the ring only.
 * **Exact agreement with the stats.**  :meth:`Tracer.complete` takes the
   *caller's* ``t0``/``t1`` perf_counter readings — the executor passes the
   very same values it adds into ``TierStats``, so a report derived from
   spans can never disagree with the counters.
 
 Spans must stay **outside jitted code**: a span inside a traced function
-fires once at trace time (the ``trace-purity`` invariant).  The executor
-therefore skips whole-program jit when tracing is enabled.
+fires once at trace time (the ``trace-purity`` invariant).  Inside jitted
+code the names are ``jax.named_scope`` scopes instead, which the profiler
+reports on the device's operations.
 """
 
 from __future__ import annotations
@@ -41,6 +51,18 @@ from typing import Optional
 
 __all__ = ["Tracer", "NoopTracer", "NOOP"]
 
+
+def _annotate(name: str, args: Optional[dict]):
+    """An entered ``jax.profiler.TraceAnnotation`` named ``name``.  JAX is
+    imported here, not with the module, so the report CLI stays
+    stdlib-only."""
+    from jax.profiler import TraceAnnotation
+
+    ann = TraceAnnotation(name, **args) if args else TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 # Event tuples: (ph, name, tid, ts_s, dur_s, cat, args)
 #   ph  — Chrome trace_event phase: "X" complete, "B"/"E" begin/end,
 #         "i" instant, "C" counter
@@ -49,11 +71,12 @@ __all__ = ["Tracer", "NoopTracer", "NOOP"]
 
 
 class _Span:
-    """Context manager for one complete ("X") span.  ``duration_s`` is
-    available after exit — benchmarks time *through* the span so their
-    numbers and the trace can never disagree."""
+    """Context manager for one complete ("X") span, annotated on the
+    profiler's clock too.  ``duration_s`` is available after exit —
+    benchmarks time *through* the span so their numbers and the trace can
+    never disagree."""
 
-    __slots__ = ("_tracer", "name", "tid", "cat", "args", "t0", "t1")
+    __slots__ = ("_tracer", "name", "tid", "cat", "args", "t0", "t1", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, tid: str,
                  cat: Optional[str], args: Optional[dict]):
@@ -66,11 +89,13 @@ class _Span:
         self.t1 = 0.0
 
     def __enter__(self) -> "_Span":
+        self._ann = _annotate(self.name, self.args)
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.t1 = time.perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
         self._tracer.complete(self.name, self.t0, self.t1, tid=self.tid,
                               cat=self.cat, **(self.args or {}))
         return False
@@ -91,7 +116,9 @@ class Tracer:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.name = name
         self.capacity = capacity
-        self.epoch = time.perf_counter() if epoch is None else epoch
+        now, now_ns = time.perf_counter(), time.time_ns()
+        self.epoch = now if epoch is None else epoch
+        self.epoch_time_ns = now_ns - round((now - self.epoch) * 1e9)
         self._events = collections.deque(maxlen=capacity)
         self.dropped = 0        # advisory: events evicted by the ring
 
@@ -109,7 +136,8 @@ class Tracer:
     def span(self, name: str, tid: str = "main", cat: Optional[str] = None,
              **args) -> _Span:
         """``with tracer.span("stage:merge", tid="stages"): ...`` — records
-        one complete span from enter to exit."""
+        one complete span from enter to exit, and annotates the same
+        interval for the profiler."""
         return _Span(self, name, tid, cat, args or None)
 
     def complete(self, name: str, t0: float, t1: float, tid: str = "main",
@@ -159,31 +187,37 @@ class Tracer:
 
 
 class _NoopSpan:
-    """Shared do-nothing span: zero allocation per disabled ``span()``."""
+    """A disabled ``span()``: records nothing in a ring, and annotates the
+    profiler only (a ``jax.profiler`` session decides whether that is
+    kept)."""
 
-    __slots__ = ()
+    __slots__ = ("name", "args", "_ann")
     t0 = 0.0
     t1 = 0.0
     duration_s = 0.0
 
+    def __init__(self, name: str, args: Optional[dict]):
+        self.name = name
+        self.args = args
+
     def __enter__(self) -> "_NoopSpan":
+        self._ann = _annotate(self.name, self.args)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
-_NOOP_SPAN = _NoopSpan()
-
-
 class NoopTracer:
-    """Disabled tracer: every method is a no-op, ``enabled`` is False so
-    hot paths can skip even argument construction.  Use the shared
-    :data:`NOOP` singleton."""
+    """Disabled tracer: every method is a no-op but :meth:`span`, which
+    annotates the profiler only; ``enabled`` is False so hot paths can skip
+    even argument construction.  Use the shared :data:`NOOP` singleton."""
 
     enabled = False
     name = "noop"
     epoch = 0.0
+    epoch_time_ns = 0
     capacity = 0
     dropped = 0
 
@@ -191,7 +225,7 @@ class NoopTracer:
         return time.perf_counter()
 
     def span(self, name: str, tid: str = "main", cat=None, **args):
-        return _NOOP_SPAN
+        return _NoopSpan(name, args or None)
 
     def complete(self, name, t0, t1, tid="main", cat=None, **args) -> None:
         pass

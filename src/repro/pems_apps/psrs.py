@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import os
 import signal
+import time
 from typing import Optional
 
 import jax
@@ -98,14 +99,21 @@ def _build(v: int, k: int, n_v: int, cap, rcap, driver: str,
                            device_cap_bytes=device_cap_bytes, **io_kw),
                 lo, mesh=mesh)
 
+    # Each stage body runs under a named scope (psrs.<stage>), so its
+    # device operations carry the stage's name in the profiler's trace, in
+    # the whole-program jit and in the tiered stage jits alike.  Scope
+    # names hold no ":" or "/": the profiler separates on both.
+    @jax.named_scope("psrs.sort_sample")
     def sort_and_sample(rho, ctx):
-        data = local_sort(ctx.get("data"))
+        with jax.named_scope("psrs.local_sort"):
+            data = local_sort(ctx.get("data"))
         # Regular sampling: positions ⌊j·n_v/v⌋, j = 0..v−1 (Shi & Schaeffer).
         idx = (jnp.arange(v) * n_v) // v
         gid = rho * n_v + idx.astype(jnp.int32)
         samp = jnp.stack([data[idx], gid], axis=-1)
         return ctx.set("data", data).set("samp", samp)
 
+    @jax.named_scope("psrs.pick_splitters")
     def pick_splitters(rho, ctx):
         allsamp = ctx.get("allsamp").reshape(-1, 2)
         order = jnp.lexsort((allsamp[:, 1], allsamp[:, 0]))
@@ -117,6 +125,7 @@ def _build(v: int, k: int, n_v: int, cap, rcap, driver: str,
         )
         return ctx.set("gsplit", gs)
 
+    @jax.named_scope("psrs.partition")
     def partition(rho, ctx):
         data = ctx.get("data")
         gs = ctx.get("gsplit")
@@ -134,6 +143,7 @@ def _build(v: int, k: int, n_v: int, cap, rcap, driver: str,
             .set("oflow", (~ok).astype(jnp.int32)[None])
         )
 
+    @jax.named_scope("psrs.merge")
     def merge(rho, ctx):
         # The boundary mask is fused into delivery (alltoallv fill=INT_MAX):
         # lanes past brcnt arrive as INT_MAX, so the received buckets merge
@@ -192,10 +202,11 @@ def _build(v: int, k: int, n_v: int, cap, rcap, driver: str,
             stream=True)),
     ]
 
-    # Stage spans on the main tracer's "stages" lane: one per plan stage,
-    # the unit the obs report attributes compute/I-O/stall time to.  With
-    # tracing off pems.tracer is the no-op singleton, so the wrapper costs
-    # one attribute check per stage (and is jit-transparent).
+    # Stage spans on the main tracer's "stages" lane, for the host-driven
+    # paths (backing tiers, the P > 1 mesh, callers of psrs_plan): one per
+    # plan stage, the unit the obs report attributes compute/I-O/stall time
+    # to.  The jitted program below runs the bare steps: there a span would
+    # fire once at trace time, and the stages' named scopes label the work.
     def _staged(name, fn):
         def run(st, procs=None):
             with pems.tracer.span(f"stage:{name}", tid="stages",
@@ -203,7 +214,7 @@ def _build(v: int, k: int, n_v: int, cap, rcap, driver: str,
                 return fn(st, procs=procs)
         return run
 
-    steps = [(name, _staged(name, fn)) for name, fn in steps]
+    staged = [(name, _staged(name, fn)) for name, fn in steps]
 
     def load(data_blocks):                  # [v, n_v] int32
         store = pems.init()
@@ -217,20 +228,21 @@ def _build(v: int, k: int, n_v: int, cap, rcap, driver: str,
         return (store.field("result"), store.field("rcount"),
                 store.field("oflow"))
 
+    # The single-process device tier jit-fuses the whole pipeline, traced
+    # or not, running the bare steps; the P > 1 mesh path runs the staged
+    # steps eagerly (each superstep/collective shard_maps and jits
+    # internally).
+    jitted = tier == "device" and P == 1
+
     def program(data_blocks):
         store = load(data_blocks)
-        for _, step in steps:
+        for _, step in (steps if jitted else staged):
             store = step(store)
         return extract(store)
 
-    # The P > 1 mesh path runs the stages eagerly (each superstep/collective
-    # shard_maps and jits internally); the single-process device tier still
-    # jit-fuses the whole pipeline as the seed did.  Tracing forces the
-    # eager path — spans inside a jitted program would fire once at trace
-    # time and never again (results are bit-identical either way).
-    if tier == "device" and P == 1 and not pems.cfg.trace:
+    if jitted:
         program = jax.jit(program)
-    return pems, program, (load, steps, extract)
+    return pems, program, (load, staged, extract)
 
 
 def psrs_plan(
@@ -266,8 +278,9 @@ def psrs_plan(
     order, or stop after any stage, checkpoint the backing store, and
     resume later); ``extract(store) -> (result, rcount, oflow)``.
 
-    ``trace=True`` records structured spans (stages, executor rounds, I/O
-    requests, collective chunks) into ``pems.tracer``; export with
+    ``trace=True`` records structured spans (stages, supersteps, executor
+    rounds, I/O requests, collective chunks) into ``pems.tracer``, the
+    steps being run from the host; export with
     ``pems.export_trace(path)`` (or set ``trace_path`` — :func:`psrs_sort`
     / :func:`psrs_run_recoverable` then export automatically).
     """
@@ -354,50 +367,65 @@ def psrs_sort(
     measured in ``pems.shard_ledgers[p]``/``pems.shard_stats[p]`` and sums
     to the ``P == 1`` totals; results stay bit-identical.
 
-    ``trace=True`` records structured spans for the whole run — per-stage
-    and per-superstep, executor rounds (compute vs swap_in/swap_out vs
-    stall), per-request engine I/O, collective chunks — in the
-    :mod:`repro.obs` tracer (device-tier ``P == 1`` then runs eagerly
-    instead of whole-program jit; results are bit-identical).  With
-    ``trace_path`` set the merged Chrome/Perfetto trace (plus a metrics
-    snapshot) is written there on completion; inspect with
-    ``python -m repro.obs report <path>``.
+    Every call records four spans on the ``calls`` lane:
+    ``call:prepare`` (keys to the device, the plan built),
+    ``call:dispatch`` (the program called: on the device tier its trace,
+    lowering, compile or cache load and enqueue; on the host-driven tiers
+    the whole run), ``call:wait`` (the results fetched to the host) and
+    ``call:extract`` (the sorted keys assembled).  They are annotations of
+    a ``jax.profiler`` session always, and enter the :mod:`repro.obs` ring
+    with ``trace=True``, which also records the host-driven paths'
+    per-stage and per-superstep spans, executor rounds (compute vs
+    swap_in/swap_out vs stall), per-request engine I/O and collective
+    chunks.  The device tier runs the same jitted program either way; its
+    stages are ``jax.named_scope`` scopes (``psrs.<stage>``) on the device
+    operations.  Results are bit-identical.  With ``trace_path`` set the
+    merged Chrome/Perfetto trace (plus a metrics snapshot) is written there
+    on completion; inspect with ``python -m repro.obs report <path>``.
 
     Raises ``ValueError`` for n not divisible by v (and for any invalid
     :class:`~repro.core.PemsConfig` combination) and ``OverflowError``
     when a bucket exceeds ``cap``/``rcap``.
     """
-    keys = jnp.asarray(keys, jnp.int32)
-    n = keys.shape[0]
-    if n % v:
-        raise ValueError(f"n={n} must be divisible by v={v}")
-    n_v = n // v
-    pems, program, _ = _build(v, k, n_v, cap, rcap, driver, mode, local_sort,
-                              use_kernel=use_kernel, tier=tier,
-                              backing_path=backing_path,
-                              device_cap_bytes=device_cap_bytes,
-                              P=P, mesh=mesh, alpha=alpha,
-                              io_driver=io_driver,
-                              io_queue_depth=io_queue_depth,
-                              fault_spec=fault_spec, checksums=checksums,
-                              io_retries=io_retries,
-                              merge_kernel=merge_kernel,
-                              merge_tile=merge_tile,
-                              trace=trace, trace_path=trace_path)
-    data = keys.reshape(v, n_v)
-    if tier != "device":
-        data = np.asarray(data)
-    result, rcount, oflow = program(data)
+    # call:prepare builds the tracer it is recorded in, so it is timed and
+    # annotated by hand; the other call spans are ordinary spans.
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation("call:prepare"):
+        keys = jnp.asarray(keys, jnp.int32)
+        n = keys.shape[0]
+        if n % v:
+            raise ValueError(f"n={n} must be divisible by v={v}")
+        n_v = n // v
+        pems, program, _ = _build(
+            v, k, n_v, cap, rcap, driver, mode, local_sort,
+            use_kernel=use_kernel, tier=tier, backing_path=backing_path,
+            device_cap_bytes=device_cap_bytes, P=P, mesh=mesh, alpha=alpha,
+            io_driver=io_driver, io_queue_depth=io_queue_depth,
+            fault_spec=fault_spec, checksums=checksums,
+            io_retries=io_retries, merge_kernel=merge_kernel,
+            merge_tile=merge_tile, trace=trace, trace_path=trace_path)
+        data = keys.reshape(v, n_v)
+        if tier != "device":
+            data = np.asarray(data)
+    tr = pems.tracer
+    tr.complete("call:prepare", t0, time.perf_counter(), tid="calls",
+                cat="call")
+    with tr.span("call:dispatch", tid="calls", cat="call", keys=n):
+        result, rcount, oflow = program(data)
+    with tr.span("call:wait", tid="calls", cat="call"):
+        result = np.asarray(result)
+        rcount = np.asarray(rcount)[:, 0]
+        overflow = bool(np.asarray(oflow).any())
+    if not overflow:
+        with tr.span("call:extract", tid="calls", cat="call"):
+            out = np.concatenate([result[i, : rcount[i]] for i in range(v)])
     if pems.cfg.trace_path is not None:
         pems.export_trace()
-    result = np.asarray(result)
-    rcount = np.asarray(rcount)[:, 0]
-    if np.asarray(oflow).any():
+    if overflow:
         raise OverflowError(
             "PSRS message capacity exceeded; raise cap/rcap "
             f"(cap={cap}, rcap={rcap})"
         )
-    out = np.concatenate([result[i, : rcount[i]] for i in range(v)])
     if return_pems:
         return out, pems
     return out

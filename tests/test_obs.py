@@ -63,6 +63,46 @@ def test_noop_tracer_is_inert():
     assert NOOP.events() == [] and len(NOOP) == 0
 
 
+def test_spans_annotate_the_profiler(tmp_path):
+    """span() enters a jax.profiler TraceAnnotation of its name, on a
+    Tracer and on NOOP alike; complete() stays in the ring only."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("obs:traced", tid="lane", n=3):
+            time.sleep(0.002)
+        with NOOP.span("obs:noop", tid="lane"):
+            time.sleep(0.002)
+        t0 = tr.now()
+        tr.complete("obs:ring_only", t0, t0 + 0.001, tid="lane")
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = [ev for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+    names = {ev.name for ev in host}
+    assert {"obs:traced", "obs:noop"} <= names
+    assert "obs:ring_only" not in names
+    assert [e[1] for e in tr.events()] == ["obs:traced", "obs:ring_only"]
+
+
+def test_tracer_epoch_reads_on_both_clocks():
+    tr = Tracer()
+    shard = Tracer(epoch=tr.epoch)
+    now_ns = time.time_ns()
+    since = time.perf_counter() - tr.epoch
+    # epoch_time_ns + (perf_counter - epoch) is the wall clock, to within
+    # the time between the readings.
+    assert abs(tr.epoch_time_ns + since * 1e9 - now_ns) < 5e6
+    assert abs(shard.epoch_time_ns - tr.epoch_time_ns) < 5e6
+
+
 def test_config_rejects_trace_path_without_trace(tmp_path):
     with pytest.raises(ValueError, match="trace_path"):
         PemsConfig(v=4, k=1, trace_path=str(tmp_path / "t.json"))
@@ -136,18 +176,31 @@ def test_psrs_trace_roundtrip(tmp_path, tier, P):
     for e in evs:
         assert {"ph", "pid", "tid", "name"} <= set(e)
     assert _lane_balance(evs) == 0
+    # Every call records its four call spans, in order, on the calls lane.
+    calls = [e for e in evs if e.get("cat") == "call"]
+    assert [e["name"] for e in calls] == [
+        "call:prepare", "call:dispatch", "call:wait", "call:extract"]
+    assert calls[1]["args"] == {"keys": _N}
     stage = [e for e in evs if e.get("cat") == "stage"]
-    assert len(stage) == _STAGES
-    assert [e["name"] for e in stage] == [
-        "stage:sort_sample", "stage:gather_samples", "stage:pick_splitters",
-        "stage:bcast_splitters", "stage:partition", "stage:alltoallv",
-        "stage:merge"]
+    sup = [e for e in evs if e.get("cat") == "superstep"]
+    if tier == "device":
+        # The device tier runs the jitted program: its stages are named
+        # scopes on the device's operations, not host spans.
+        assert stage == [] and sup == []
+    else:
+        assert len(stage) == _STAGES
+        assert [e["name"] for e in stage] == [
+            "stage:sort_sample", "stage:gather_samples",
+            "stage:pick_splitters", "stage:bcast_splitters",
+            "stage:partition", "stage:alltoallv", "stage:merge"]
+        assert len(sup) == 4                   # the four compute supersteps
     pids = {e["pid"] for e in evs}
     # pid 0 is the main tracer; disk tiers add one lane per shard process.
     assert pids == ({0} if tier == "device" else {0, *range(1, P + 1)})
     assert "metrics" in trace
-    sup = [e for e in evs if e.get("cat") == "superstep"]
-    assert len(sup) == 4                       # the four compute supersteps
+    # The tracers' epoch on both clocks, to place ring events on a
+    # profiler trace's clock.
+    assert set(trace["clock"]) == {"perf_counter_s", "time_ns"}
     if tier != "device":
         assert any(e.get("cat") == "compute" for e in evs)
         assert any(e.get("cat") == "io" for e in evs)
