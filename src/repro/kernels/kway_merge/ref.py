@@ -5,10 +5,15 @@ its bucket's count to ``fill``, sort the whole ``v·cap`` population flat, and
 keep the lowest ``rcap`` values (``fill``-padded when the population is
 smaller than ``rcap``).  This is exactly what PSRS's seed merge stage
 computed with ``jnp.sort(recv.reshape(-1))[:rcap]`` on fill-masked buckets.
+
+``exact_starts_ref`` is the splitter search's oracle: the same value-domain
+search as ``ops._exact_starts``, each count a scalar binary search
+(``jnp.searchsorted``) instead of the fence index.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -25,3 +30,27 @@ def kway_merge_ref(buckets: jnp.ndarray, counts: jnp.ndarray, *,
         return flat[:rcap]
     pad = jnp.full((rcap - flat.shape[0],), fill, buckets.dtype)
     return jnp.concatenate([flat, pad])
+
+
+def exact_starts_ref(rows_u32: jnp.ndarray, ranks: jnp.ndarray) -> jnp.ndarray:
+    """``starts [R, v]`` for global ``ranks`` over ``v`` ascending uint32
+    rows, by binary search per count: ``t = max u: #{x < u} < rank`` found
+    MSB first, then the ``rank − #{x < t}`` duplicates of ``t`` assigned
+    greedily in bucket order."""
+    ranks = ranks.astype(jnp.int32)
+
+    def per_row(vals, side):                  # [R] → [v, R]
+        return jax.vmap(lambda row: jnp.searchsorted(row, vals, side=side)
+                        )(rows_u32).astype(jnp.int32)
+
+    def bit_step(i, u):
+        cand = u | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        return jnp.where(per_row(cand, "left").sum(axis=0) < ranks, cand, u)
+
+    u = jax.lax.fori_loop(0, 32, bit_step,
+                          jnp.zeros(ranks.shape, jnp.uint32))
+    lo, hi = per_row(u, "left"), per_row(u, "right")
+    dups = hi - lo
+    need = ranks[None, :] - lo.sum(axis=0, keepdims=True)
+    cum = jnp.cumsum(dups, axis=0) - dups
+    return (lo + jnp.clip(need - cum, 0, dups)).T

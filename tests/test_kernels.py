@@ -11,7 +11,9 @@ from repro.kernels.alltoallv_deliver.ref import deliver_ref
 from repro.kernels.bitonic_sort.ops import sort as bitonic_sort
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
+from repro.kernels.kway_merge import ops as kway_merge_ops
 from repro.kernels.kway_merge import (
+    exact_starts_ref,
     kway_merge,
     kway_merge_ref,
     merge_tile_grid,
@@ -526,6 +528,66 @@ def test_kway_merge_property(seed, v, tile):
     np.testing.assert_array_equal(np.asarray(merged), np.asarray(ref))
     assert int(total) == int(counts.sum())
     assert bool(over) == (int(counts.sum()) > rcap)
+
+
+# cap values: one index level (≤128 lanes), two, three, and padded tails.
+FENCE_CAPS = [1, 17, 127, 128, 129, 128 * 128, 128 * 128 + 1]
+U32_MAX = 0xFFFFFFFF
+
+
+def _fence_rows(cap, rng):
+    """Ascending uint32 rows: random, heavy duplicates, all fill, and a
+    valid prefix followed by fill lanes."""
+    rand = np.sort(rng.integers(0, 2**32, size=cap, dtype=np.uint64)
+                   ).astype(np.uint32)
+    dups = np.sort(rng.integers(0, 3, size=cap)).astype(np.uint32) + 7
+    fill = np.full(cap, U32_MAX, np.uint32)
+    tail = rand.copy()
+    tail[cap // 2:] = U32_MAX
+    return np.stack([rand, dups, fill, tail])
+
+
+@pytest.mark.parametrize("cap", FENCE_CAPS)
+@pytest.mark.parametrize("side", ["left", "right"])
+def test_fence_index_counts_match_searchsorted(cap, side):
+    """Per-row counts through the fence index equal np.searchsorted
+    exactly: #{x < q} for side="left", #{x ≤ q} for side="right"."""
+    rng = np.random.default_rng(cap)
+    rows = _fence_rows(cap, rng)
+    q = np.concatenate([
+        [0, U32_MAX, U32_MAX - 1, 6, 7, 8, 9, 10],
+        rows[0][::max(1, cap // 9)], rows[0][::max(1, cap // 9)] + 1,
+        rng.integers(0, 2**32, size=40, dtype=np.uint64),
+    ]).astype(np.uint32)
+    index = kway_merge_ops._fence_index(jnp.asarray(rows))
+    if side == "left":
+        got = kway_merge_ops._count_lt(index, jnp.asarray(q))
+    else:
+        got = kway_merge_ops._count_le(index, jnp.asarray(q), cap)
+    want = np.stack([np.searchsorted(r, q, side=side) for r in rows])
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("cap", FENCE_CAPS)
+@pytest.mark.parametrize("kind", ["random", "dups", "fillmax", "presorted"])
+def test_exact_starts_match_binary_search_oracle(cap, kind):
+    """The splitter search's starts equal the scalar binary search's
+    (``exact_starts_ref``) element for element, every rank of a tile grid
+    including the all-fill tail."""
+    v, tile = 3, 8
+    buckets, counts = _merge_case(v, cap, np.int32, kind,
+                                  rng=np.random.default_rng(cap + 1))
+    lane = np.arange(cap)
+    masked = np.where(lane[None, :] < counts[:, None], buckets,
+                      np.iinfo(np.int32).max)
+    rows = kway_merge_ops._to_biased_u32(jnp.asarray(masked))
+    ranks = jnp.minimum(jnp.arange(-(-2 * v * cap // tile) + 1) * tile,
+                        v * cap)
+    got = kway_merge_ops._exact_starts(rows, ranks)
+    want = exact_starts_ref(rows, ranks)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got).sum(axis=1),
+                                  np.asarray(ranks))
 
 
 def test_psrs_bit_identical_across_merge_kernel():

@@ -16,8 +16,8 @@ from repro.pems_apps import psrs, psrs_sort
 _N, _V, _K = 2048, 8, 2
 SCOPES = ("psrs.sort_sample", "psrs.local_sort", "psrs.pick_splitters",
           "psrs.partition", "psrs.merge", "pems.gather", "pems.bcast",
-          "pems.alltoallv", "kway_merge.splitters", "kway_merge.gather",
-          "kway_merge.tiles")
+          "pems.alltoallv", "kway_merge.splitters", "kway_merge.index",
+          "kway_merge.gather", "kway_merge.tiles")
 
 
 def _keys(seed=5):

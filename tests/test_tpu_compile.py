@@ -21,7 +21,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.alltoallv_deliver import assemble_proc_tiles, deliver_tiles
 from repro.kernels.bitonic_sort.bitonic_sort import bitonic_sort_rows
 from repro.kernels.bitonic_sort.ops import KERNEL_MAX_N
-from repro.kernels.kway_merge import merge_tile_grid
+from repro.kernels.kway_merge import kway_merge, merge_tile_grid
 
 INT_MAX = 2**31 - 1
 V, K = 16, 4
@@ -106,6 +106,20 @@ def test_merge_tile_grid_compiles(one_chip, dtype):
     T = jax.ShapeDtypeStruct((K, MERGE_TILES, 256), dtype, sharding=one_chip)
     text = _compile_text(jax.vmap(merge_tile_grid), T)
     _assert_kernel(text, "kway_merge")
+
+
+def test_kway_merge_compiles(one_chip):
+    """The whole merge stage at the class-A cell's shape (NPB IS class A,
+    v=16, k=4: [k, v, 2^19] receive buckets, rcap 2^20, tile 256),
+    vmapped over the k resident contexts: the splitter search's fence
+    index and block gathers lower, and the tile merge stays a kernel
+    (``interpret=False``: the dispatch the chip's backend takes)."""
+    cap = (1 << 23) // V
+    B = jax.ShapeDtypeStruct((K, V, cap), jnp.int32, sharding=one_chip)
+    C = jax.ShapeDtypeStruct((K, V), jnp.int32, sharding=one_chip)
+    merge = jax.vmap(lambda b, c: kway_merge(b, c, rcap=2 * cap, tile=256,
+                                             fill=INT_MAX, interpret=False))
+    _assert_kernel(_compile_text(merge, B, C), "kway_merge")
 
 
 @pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
