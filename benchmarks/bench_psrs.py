@@ -12,8 +12,9 @@ Three instrumented sections land in ``BENCH_psrs.json``
   ``merge_s``; ``merge_dense_s`` is the same merge stage re-timed with
   ``merge_kernel=False`` for the end-to-end view of what the kernel buys.
 * ``merge`` — the *paired-sample* kernel-vs-dense statistic the regression
-  gate holds: on authentic post-delivery buckets (the real ``brecv`` /
-  ``brcnt`` extracted after running the plan through alltoallv), the tiled
+  gate holds: on authentic post-delivery buckets (the runs of the real
+  packed ``brecv``, cut by its ``roff`` offsets after running the plan
+  through alltoallv), the tiled
   k-way merge and the seed's dense ``jnp.sort(flat)[:rcap]`` re-sort run
   interleaved in the same process; ``speedup_vs_dense`` is the median of
   per-iteration (dense / kernel) wall-time ratios, so machine speed
@@ -38,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import analysis
-from repro.kernels.kway_merge import kway_merge
+from repro.kernels.kway_merge import kway_merge, unpack_runs
 from repro.pems_apps import psrs_plan, psrs_sort
 from repro.pems_apps.common import INT_MAX
 from .common import TRACER, emit, time_fn
@@ -52,7 +53,7 @@ _STAGE_SYNC = {
     "gather_samples": "allsamp",
     "pick_splitters": "gsplit",
     "bcast_splitters": "gsplit",
-    "partition": "bsend",
+    "partition": "boff",
     "alltoallv": "brecv",
     "merge": "result",
 }
@@ -130,9 +131,14 @@ def _merge_pair_row(n: int, v: int, k: int, tile: int, rng,
                                     dtype=np.int32))
     _, load, steps, _ = psrs_plan(v, n_v, k)
     store = _run_steps(load, steps, data, until="alltoallv")
-    brecv = jax.block_until_ready(store.field("brecv"))    # [v, v, cap]
-    brcnt = jax.block_until_ready(store.field("brcnt"))    # [v, v]
-    cap, rcap = brecv.shape[-1], 2 * n_v
+    rcap = 2 * n_v
+    runs, brcnt = jax.vmap(lambda r, o: unpack_runs(r, o, n_v))(
+        store.field("brecv"), store.field("roff"))
+    cap = runs.shape[-1]                                   # [v, v, n_v]
+    # Buckets with INT_MAX past their counts, as the slab delivery left
+    # them, so both sides of the pair see the same input as before.
+    brecv = jax.block_until_ready(jnp.where(
+        jnp.arange(cap) < brcnt[..., None], runs, INT_MAX))
 
     f_kernel = jax.jit(jax.vmap(
         lambda b, c: kway_merge(b, c, rcap=rcap, tile=tile,

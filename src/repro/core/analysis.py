@@ -10,6 +10,7 @@ simulation (``repro.core``) can be validated *exactly* against the paper:
 * Lemma 7.3.1 / Thm 7.3.3              — EM-Gather
 * Lemma 7.4.2 / Thm 7.4.4              — EM-Reduce
 * §6.3 / Fig 6.2                       — disk-space requirements
+* the packed PSRS context: its size μ and a file-tier job's disk bytes
 
 All byte quantities share one unit (bytes); time models are parameterised by
 the EM-BSP coefficients (Appendix B.4): S, G (seconds per block of size B),
@@ -170,6 +171,45 @@ def pems2_alltoallv_par_network_rounds(v: int, P: int, k: int,
 def pems2_disk_space(v: int, P: int, mu: int) -> int:
     """§6.3: PEMS2 needs exactly vμ/P per real processor (no indirect area)."""
     return v * mu // P
+
+
+def psrs_context_words(v: int, n_v: int, rcap=None) -> int:
+    """Words of one packed PSRS context (``repro.pems_apps.psrs``): the
+    sorted ``data`` (n/v), the packed receive field and the result (``rcap``
+    each, 2n/v by default), the samples and splitters (2v + 2v² + 2v) and
+    the two ``[v + 1]`` offset tables — about 5 words a key, no ``[v, ω]``
+    slab."""
+    rcap = 2 * n_v if rcap is None else rcap
+    return n_v + 2 * rcap + 2 * v * v + 6 * v + 2
+
+
+def psrs_disk_bytes(v: int, n_v: int, driver: str, rcap=None):
+    """``(read, write)`` disk bytes of one PSRS job on a disk tier, as the
+    I/O ledger bills them (any ``P``, shards summed; no receive overflow):
+    the load, four supersteps, gather, bcast, the packed Alltoallv and the
+    extraction.  ``explicit``/``async`` swap whole contexts in and out;
+    ``sliced`` moves only the fields a superstep declares.  The Alltoallv
+    reads the senders' offsets and each message's live words and writes
+    each receiver's live words and offsets: n words each way."""
+    W = 4
+    rcap = 2 * n_v if rcap is None else rcap
+    mu = psrs_context_words(v, n_v, rcap)
+    n, offs = v * n_v, v * (v + 1)
+    if driver == "sliced":   # (reads, writes) words of each superstep
+        steps = [(n, n + 2 * v * v),                    # sort_sample
+                 (2 * v ** 3, 2 * v * v),               # pick_splitters
+                 (n + 2 * v * v, offs),                 # partition
+                 (v * rcap + offs, v * rcap)]           # merge
+    else:
+        steps = [(v * mu, v * mu)] * 4
+    read = sum(r for r, _ in steps)
+    write = sum(w for _, w in steps)
+    read += 2 * v * v + 2 * v                 # gather samp; bcast's root row
+    write += n + 2 * v * v + 2 * v * v        # load; gather; bcast
+    read += offs + n                          # alltoallv
+    write += n + offs
+    read += v * rcap + offs                   # extract: result, totals
+    return read * W, write * W
 
 
 # --------------------------------------------------------------------------- #

@@ -47,6 +47,15 @@ network phase is α-chunked (Alg 7.1.3) into one ``[k, P, α, ω]`` buffer per
 Lemma 7.1.9 bound — delivered in place chunk by chunk.  ``use_kernel=False``
 keeps the dense route for equivalence testing.
 
+The packed form (``offsets=``: 1-D ``send``/``recv`` fields with ``[v +
+1]`` offset fields, MPI's displacement form) moves variable-length messages without a
+``[v, ω]`` slab in any context: each sender's messages are contiguous
+slices of one field, and each receiver's arrive end to end in one field.
+On the device it stages transient tiles through the same delivery kernel
+(:func:`repro.kernels.alltoallv_deliver.deliver_packed`, or the
+assemble-and-ship route over the mesh); on a backing tier it reads each
+message's live words and writes each receiver's live words, nothing else.
+
 The I/O ledger is updated with *event-level* counts that tests validate
 against the closed forms in :mod:`repro.core.analysis`; the delivery
 implementation (kernel vs dense, masked vs not) never changes the event
@@ -56,7 +65,7 @@ execution strategy.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -83,12 +92,25 @@ def alltoallv(
     fill=None,
     use_kernel: bool = True,
     procs: Optional[list] = None,
+    offsets: Optional[Tuple[str, str]] = None,
 ) -> ContextStore:
     """Every VP ρ sends message ``send[d]`` to VP d; after the call VP ρ holds
     ``recv[s] =`` (s's message to ρ) and transposed counts.
 
     ``send``/``recv`` name ``[v, ω]`` layout fields (``ω`` the per-message
-    payload; all byte math below is ``ω`` words × 4 bytes).  ``fill``
+    payload; all byte math below is ``ω`` words × 4 bytes).
+
+    ``offsets=(send_off, recv_off)`` selects the packed form instead, in
+    place of the counts: ``send``/``recv`` are 1-D fields and the two names
+    ``[v + 1]`` offset fields.  VP ρ's message to d is ``send[send_off[d]:
+    send_off[d + 1]]``; after the call VP ρ's ``recv`` holds the messages
+    from VP 0, 1, ... end to end, ``recv_off[s]`` where s's message starts
+    and ``recv_off[v]`` the total.  A total past the ``recv`` field is
+    recorded in full but only the field's words are stored: the caller
+    checks it.  Words past the total are ``fill`` on the device tiers and
+    left as they were on the backing tiers.
+
+    ``fill``
     (optional, requires counts) fuses the receiver's boundary mask into
     delivery: lanes past ``send_counts[ρ][d]`` arrive as ``fill`` instead of
     whatever padding the sender left.  ``use_kernel=False`` keeps the seed's
@@ -108,13 +130,18 @@ def alltoallv(
     a rerun would re-read already-shuffled source rows.
 
     Raises ``ValueError`` for unknown ``mode``, mismatched field shapes,
-    ``fill`` without counts, ``procs`` on a device store, or a staging
-    chunk that cannot fit ``device_cap_bytes``.
+    ``fill`` without counts, ``offsets`` with counts, ``procs`` on a device
+    store, or a staging chunk that cannot fit ``device_cap_bytes``.
     """
     if mode not in ("direct", "indirect"):
         raise ValueError(f"unknown mode {mode!r}")
     if procs is not None and not isinstance(store, TieredStore):
         raise ValueError("procs= requires a backing-tier store")
+    if offsets is not None:
+        if send_counts is not None or recv_counts is not None:
+            raise ValueError("offsets= replaces send_counts/recv_counts")
+        return _alltoallv_packed(self, store, send, recv, *offsets, mode,
+                                 fill, use_kernel, procs)
     cfg = self.cfg
     f = store.layout.field(send)
     if store.layout.field(recv).shape != f.shape:
@@ -272,8 +299,6 @@ def _alltoallv_fused_mesh(self, store, send, recv, send_counts, recv_counts,
     knob exists for memory-bounded staging (and the tiered ``P > 1`` path
     to come), not for speed.
     """
-    from repro.kernels.alltoallv_deliver import assemble_proc_fused
-
     cfg = self.cfg
     lo = store.layout
     v, Pn, m, k = cfg.v, cfg.P, cfg.v_local, cfg.k
@@ -295,19 +320,6 @@ def _alltoallv_fused_mesh(self, store, send, recv, send_counts, recv_counts,
             return ct
         return _to_words(_from_words(ct, cs).astype(cr))
 
-    def ship(xc, cm, cp):
-        """Assemble one chunk [s, P, d, ww] into destination order (mask +
-        counts transpose fused), all_to_all payload and counts, returning
-        payload [d, P, s, ww] and counts words [d, P, s] (or None) — both
-        already in the destination rows' slot order."""
-        out, ct = assemble_proc_fused(xc, cm, cp, fill=fill_word)
-        y = lax.all_to_all(out, cfg.vp_axis, split_axis=0,
-                           concat_axis=1, tiled=False)  # [d, P(src), s, ww]
-        if ct is None:
-            return y, None
-        yc = lax.all_to_all(ct, cfg.vp_axis, split_axis=0,
-                            concat_axis=1, tiled=False)  # [d, P(src), s]
-        return y, yc
 
     def f(local):                              # [m, words]: this proc's rows
         # Word-level send matrix: W[sl, dp, dl] is row sl's ω-words for
@@ -324,7 +336,7 @@ def _alltoallv_fused_mesh(self, store, send, recv, send_counts, recv_counts,
 
         if alpha is None:
             # Unchunked: one assembly, one all_to_all, one row landing.
-            pay, ct = ship(W, C_i, C_w)        # [m, P, m, ww], [m, P, m]
+            pay, ct = _ship_chunk(cfg, W, C_i, C_w, fill_word)  # [m, P, m, ww]
             if m * v * ww <= _MESH_DUS_MAX_WORDS:
                 new = lax.dynamic_update_slice(
                     local, pay.reshape(m, v * ww), (0, off_r))
@@ -351,7 +363,7 @@ def _alltoallv_fused_mesh(self, store, send, recv, send_counts, recv_counts,
                     cp = C_w[s0:s0 + k, :, c0:c1]
                     if fill is not None:
                         cm = C_i[s0:s0 + k, :, c0:c1]
-                pay, ct = ship(xc, cm, cp)     # [c, P, k, ww], [c, P, k]
+                pay, ct = _ship_chunk(cfg, xc, cm, cp, fill_word)  # [c, P, k, ww]
                 if has_counts:
                     ct = conv_ct(ct)
                 # Land in place: each source process' slots are a
@@ -374,6 +386,24 @@ def _alltoallv_fused_mesh(self, store, send, recv, send_counts, recv_counts,
         out_specs=P(cfg.vp_axis, None),
     )(store.data)
     return ContextStore(lo, data)
+
+
+def _ship_chunk(cfg, xc, cm, cp, fill_word, use_kernel=True):
+    """Assemble one chunk ``[s, P, d, ww]`` into destination order (mask +
+    counts transpose fused), all_to_all payload and counts, returning
+    payload ``[d, P, s, ww]`` and counts ``[d, P, s]`` (or None) — both
+    already in the destination rows' slot order."""
+    from repro.kernels.alltoallv_deliver import assemble_proc_fused
+
+    out, ct = assemble_proc_fused(xc, cm, cp, fill=fill_word,
+                                  use_kernel=use_kernel)
+    y = lax.all_to_all(out, cfg.vp_axis, split_axis=0,
+                       concat_axis=1, tiled=False)      # [d, P(src), s, ww]
+    if ct is None:
+        return y, None
+    yc = lax.all_to_all(ct, cfg.vp_axis, split_axis=0,
+                        concat_axis=1, tiled=False)     # [d, P(src), s]
+    return y, yc
 
 
 def _alltoallv_dense(self, store, send, recv, send_counts, recv_counts,
@@ -457,22 +487,13 @@ def _alltoallv_host(self, store, send, recv, send_counts, recv_counts, fill,
     if fill is not None:
         fill_word = _fill_word(fill, lo.field(send).dtype)
 
-    alpha = m if cfg.alpha is None else cfg.alpha
     # Host/memmap chunks are sliced as views; the engine-backed file tier's
     # read_block returns a *copy* the same size as the staging buffer, so a
     # chunk there holds 2x its column bytes resident (copy + blk).  The
     # in-place path slices views off the snapshot either way.
     chunk_copies = 1 if (arr is not None or send == recv) else 2
-    if cfg.device_cap_bytes is not None:
-        per_dst = chunk_copies * v * ww * WORD     # one destination column
-        if per_dst > cfg.device_cap_bytes:
-            raise ValueError(
-                f"alltoallv staging needs {per_dst:,} bytes per destination "
-                f"([v, ω] = [{v}, {ww * WORD}B] x{chunk_copies}) but "
-                f"device_cap_bytes={cfg.device_cap_bytes:,}; raise the cap "
-                "or shrink ω"
-            )
-        alpha = min(alpha, cfg.device_cap_bytes // per_dst)
+    alpha = _staging_alpha(cfg, chunk_copies * v * ww * WORD,
+                           f"[v, ω] = [{v}, {ww * WORD}B] x{chunk_copies}")
     full = None
     if send == recv:
         # In-place shuffle: later chunks would read rows already
@@ -512,6 +533,21 @@ def _alltoallv_host(self, store, send, recv, send_counts, recv_counts, fill,
     return store
 
 
+def _staging_alpha(cfg, per_dst: int, what: str) -> int:
+    """Destinations a host-staged chunk holds: ``alpha`` (every local
+    context when unset), clamped so ``per_dst`` staging bytes each fit
+    ``device_cap_bytes``; a cap below one destination raises."""
+    alpha = cfg.v_local if cfg.alpha is None else cfg.alpha
+    if cfg.device_cap_bytes is None:
+        return alpha
+    if per_dst > cfg.device_cap_bytes:
+        raise ValueError(
+            f"alltoallv staging needs {per_dst:,} bytes per destination "
+            f"({what}) but device_cap_bytes={cfg.device_cap_bytes:,}; "
+            "raise the cap")
+    return min(alpha, cfg.device_cap_bytes // per_dst)
+
+
 def _alltoallv_proc_chunks(self, p, m, v, ww, alpha, arr, full, disk,
                            off_s, off_r, fill, fill_word, Ct, bk, stats,
                            chunk_copies):
@@ -549,6 +585,204 @@ def _alltoallv_proc_chunks(self, p, m, v, ww, alpha, arr, full, disk,
             if disk:
                 # The writes land entirely in destination shard p.
                 self._account_disk(c0, c1, v * ww * WORD, write=True)
+
+
+def _alltoallv_packed(self, store, send, recv, send_offsets, recv_offsets,
+                      mode, fill, use_kernel, procs):
+    """The packed form of :func:`alltoallv` (``offsets=``: 1-D message
+    fields with ``[v + 1]`` offset fields); arguments as there."""
+    cfg = self.cfg
+    lo = store.layout
+    fs, fr = lo.field(send), lo.field(recv)
+    if len(fr.shape) != 1 or fr.dtype != fs.dtype:
+        raise ValueError(
+            f"a packed alltoallv needs 1-D send/recv fields of one dtype; "
+            f"got {fs.shape} {fs.dtype.name} and {fr.shape} {fr.dtype.name}")
+    for name in (send_offsets, recv_offsets):
+        if name is None or lo.field(name).shape != (cfg.v + 1,):
+            raise ValueError(
+                f"a packed alltoallv needs [v + 1] offset fields, got "
+                f"{name!r}")
+    if fill is not None:
+        from repro.kernels.alltoallv_deliver import check_fill_range
+        check_fill_range(fill, fs.dtype)
+    fill_word = 0 if fill is None else int(_fill_word(fill, fs.dtype))
+    if isinstance(store, TieredStore):
+        store = _alltoallv_packed_host(self, store, send, recv, send_offsets,
+                                       recv_offsets, procs)
+    else:
+        with jax.named_scope("pems.alltoallv"):
+            store = _alltoallv_packed_device(
+                self, store, send, recv, send_offsets, recv_offsets,
+                mode, fill_word, use_kernel)
+    # The thesis' event counts, with the receive field's share per source
+    # as the message bound ω.
+    _ledger_alltoallv(self, fr.words * WORD // cfg.v, mode)
+    return store
+
+
+def _alltoallv_packed_device(self, store, send, recv, send_offsets,
+                             recv_offsets, mode, fill_word, use_kernel):
+    """Device tiers: each process stages its contexts' messages as
+    transient tiles, delivers them (the kernel at ``P == 1``; assembled,
+    then shipped by ``all_to_all`` over the mesh), and packs what its
+    contexts received into their ``recv`` words.  ``mode="indirect"``
+    holds the sent words behind a barrier (the PEMS1 indirect area) and
+    takes the dense route, as ``use_kernel=False`` does."""
+    from repro.kernels.alltoallv_deliver import (deliver_packed, pack_slices,
+                                                 stage_slices)
+
+    cfg = self.cfg
+    lo = store.layout
+    v, m = cfg.v, cfg.v_local
+    n_s, size = lo.field_words(send), lo.field_words(recv)
+    width = min(n_s, size)              # bounds every message that can land
+    off_s, off_so = lo.offset(send), lo.offset(send_offsets)
+    off_r, off_ro = lo.offset(recv), lo.offset(recv_offsets)
+    kernel = use_kernel and mode == "direct"
+
+    def exchange(local):                       # [m, words]: this proc's rows
+        offs = _from_words(
+            lax.slice(local, (0, off_so), (m, off_so + v + 1)), jnp.int32)
+        words = lax.slice(local, (0, off_s), (m, off_s + n_s))
+        if mode == "indirect":
+            words = lax.optimization_barrier(words)
+        if cfg.P == 1:
+            packed, roff = deliver_packed(words, offs, size, fill=fill_word,
+                                          use_kernel=kernel)
+        else:
+            counts = offs[:, 1:] - offs[:, :-1]             # [m(src), v(dst)]
+            tiles = stage_slices(words, offs, width)
+            out, got = _ship_packed_mesh(self, tiles, counts, fill_word,
+                                         kernel)
+            packed, roff = pack_slices(out, got, size, jnp.uint32(fill_word))
+        local = lax.dynamic_update_slice(local, packed, (0, off_r))
+        return lax.dynamic_update_slice(local, _to_words(roff), (0, off_ro))
+
+    if cfg.P == 1:
+        return ContextStore(lo, exchange(store.data))
+    # check_vma=False: the staging and packing loops start their carries
+    # from constants, which vary over the vp axis only once written.
+    data = jax.shard_map(
+        exchange,
+        mesh=self.mesh,
+        in_specs=(P(cfg.vp_axis, None),),
+        out_specs=P(cfg.vp_axis, None),
+        check_vma=False,
+    )(store.data)
+    return ContextStore(lo, data)
+
+
+def _ship_packed_mesh(self, tiles, counts, fill_word, kernel):
+    """The network phase of the packed delivery over the ``P > 1`` mesh:
+    this process's staged tiles ``[m(src), v(dst), width]`` are assembled
+    in destination order (mask fused) and shipped by ``all_to_all``,
+    α-chunked as in :func:`_alltoallv_fused_mesh`.  Returns the tiles its
+    contexts received ``[m(dst), v(src), width]`` and their counts
+    ``[m(dst), v(src)]``."""
+    cfg = self.cfg
+    Pn, m, k = cfg.P, cfg.v_local, cfg.k
+    width = tiles.shape[-1]
+    x = tiles.reshape(m, Pn, m, width)
+    c = counts.reshape(m, Pn, m)
+
+    def ship(xc, cc):                          # [s, P, d, w], [s, P, d]
+        return _ship_chunk(cfg, xc, cc, cc, fill_word, use_kernel=kernel)
+
+    if cfg.alpha is None:
+        y, yc = ship(x, c)
+    else:
+        rows = []
+        for c0 in range(0, m, cfg.alpha):      # destination α-chunks
+            c1 = min(c0 + cfg.alpha, m)
+            parts = [ship(x[s0:s0 + k, :, c0:c1], c[s0:s0 + k, :, c0:c1])
+                     for s0 in range(0, m, k)]   # source rounds of k
+            rows.append((jnp.concatenate([p[0] for p in parts], axis=2),
+                         jnp.concatenate([p[1] for p in parts], axis=2)))
+        y = jnp.concatenate([r[0] for r in rows], axis=0)
+        yc = jnp.concatenate([r[1] for r in rows], axis=0)
+    return y.reshape(m, Pn * m, width), yc.reshape(m, Pn * m)
+
+
+def _alltoallv_packed_host(self, store, send, recv, send_offsets,
+                           recv_offsets, procs):
+    """Backing tiers: per destination process, then by α-chunks of its
+    contexts, stage each destination's incoming messages read straight
+    from the senders' rows (the live words of each message, billed to the
+    sender's shard) and write the destination's live words (billed to its
+    shard), then its offsets.  Nothing past a receiver's total is read or
+    written.  ``device_cap_bytes`` bounds the staging per chunk as in
+    :func:`_alltoallv_host`."""
+    cfg = self.cfg
+    v, m = cfg.v, cfg.v_local
+    lo = store.layout
+    bk = store.backing
+    arr = (None if getattr(bk, "checksum", None) is not None
+           else getattr(bk, "arr", None))
+    disk = store.on_disk
+    size = lo.field_words(recv)
+    off_s, off_r = lo.offset(send), lo.offset(recv)
+    procs = list(range(cfg.P)) if procs is None else list(procs)
+
+    offs = store.field(send_offsets).astype(_np.int64)      # [v(src), v+1]
+    counts = offs[:, 1:] - offs[:, :-1]                     # [src, dst]
+    roff = _np.zeros((v, v + 1), _np.int64)
+    roff[:, 1:] = _np.cumsum(counts.T, axis=1)              # [dst, v+1]
+    live = _np.minimum(roff[:, v], size)                    # words stored
+
+    # A block read (file tier) returns a copy beside the staging row.
+    chunk_copies = 1 if arr is not None else 2
+    alpha = _staging_alpha(cfg, chunk_copies * size * WORD,
+                           f"a {size * WORD:,} B receive field "
+                           f"x{chunk_copies}")
+
+    for p in procs:
+        stats = self.shard_stats[p]
+        with self.tracer.span(f"alltoallv.p{p}", tid="collective",
+                              cat="collective", alpha=alpha):
+            for c0 in range(p * m, (p + 1) * m, alpha):
+                c1 = min(c0 + alpha, (p + 1) * m)
+                nbytes = int(live[c0:c1].sum()) * WORD
+                with self.tracer.span("chunk", tid="collective",
+                                      cat="collective", dst=p, c0=c0,
+                                      bytes=nbytes):
+                    blk = _np.empty((c1 - c0, size), _np.uint32)
+                    for d in range(c0, c1):
+                        _stage_packed_row(self, blk[d - c0], d, offs, arr,
+                                          bk, off_s, disk)
+                        if live[d]:
+                            bk.write_block(
+                                d, d + 1, blk[d - c0:d - c0 + 1, :live[d]],
+                                cols=slice(off_r, off_r + int(live[d])))
+                            if disk:
+                                self._account_disk(d, d + 1,
+                                                   int(live[d]) * WORD,
+                                                   write=True)
+                    stats.peak_stage_bytes = max(stats.peak_stage_bytes,
+                                                 chunk_copies * blk.nbytes)
+    ro = roff.astype(lo.field(recv_offsets).dtype)
+    for p in procs:
+        store.with_field_rows(recv_offsets, p * m, ro[p * m:(p + 1) * m])
+    return store
+
+
+def _stage_packed_row(self, row, d, offs, arr, bk, off_s, disk):
+    """Fill ``row`` with destination ``d``'s incoming messages end to end,
+    reading each sender's slice for ``d`` (cut where ``row`` ends)."""
+    pos = 0
+    for s in range(offs.shape[0]):
+        a = int(offs[s, d])
+        n = min(int(offs[s, d + 1]) - a, row.size - pos)
+        if n <= 0:
+            continue
+        if arr is not None:
+            row[pos:pos + n] = arr[s, off_s + a:off_s + a + n]
+        else:
+            row[pos:pos + n] = bk.read_block(
+                s, s + 1, cols=slice(off_s + a, off_s + a + n))[0]
+        if disk:
+            self._account_disk(s, s + 1, n * WORD, write=False)
+        pos += n
 
 
 def _global_transpose(self, M: jnp.ndarray) -> jnp.ndarray:

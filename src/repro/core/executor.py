@@ -65,6 +65,17 @@ from .iostats import IOLedger, TierStats
 
 DRIVERS = ("explicit", "sliced", "async")
 
+# Jitted tiered round bodies of every executor: stage function -> {(layout,
+# k) -> body} (see Pems._tiered_body).
+_TIERED_BODIES = weakref.WeakKeyDictionary()
+
+
+def _layout_key(lo: ContextLayout) -> tuple:
+    """What a round body's trace reads of a layout: each field's name,
+    offset, shape and dtype, and the context's words."""
+    return (lo.words,) + tuple((n, lo.offset(n), lo.field(n).shape,
+                                lo.field(n).dtype.name) for n in lo.names)
+
 
 @dataclasses.dataclass
 class PemsConfig:
@@ -617,13 +628,18 @@ class Pems:
         # identity, so a fresh closure here would re-trace and recompile the
         # stage on *every* superstep call (ruinous for big traces like the
         # unrolled k-way merge network).  Everything else the trace depends
-        # on is either fixed per executor (lo, k), a runtime argument
+        # on is the layout and k (in the key below), a runtime argument
         # (rw, in_j/out_j — index *contents* never shape a trace), or part
         # of jit's own cache key (shapes; None-ness via pytree structure).
-        cache = getattr(self, "_tiered_body_cache", None)
-        if cache is None:
-            cache = self._tiered_body_cache = weakref.WeakKeyDictionary()
-        body = cache.get(fn)
+        # The cache outlives the executor, so a later job that runs the
+        # same stage function on the same layout neither traces nor lowers.
+        try:
+            cache = _TIERED_BODIES.setdefault(fn, {})
+            stage = weakref.ref(fn)    # the cache must not keep fn alive
+        except TypeError:          # fn not weakref-able: run uncached
+            cache, stage = {}, lambda: fn
+        key = (_layout_key(lo), k)
+        body = cache.get(key)
         if body is None:
             @jax.jit
             def body(rho0, rw, in_i, out_i):   # rw: [k, n_in] uint32
@@ -639,7 +655,7 @@ class Pems:
                         w = jnp.zeros((lo.words,), jnp.uint32).at[in_i].set(
                             r, indices_are_sorted=True, unique_indices=True
                         )
-                    out = fn(rho, Ctx(lo, w)).words
+                    out = stage()(rho, Ctx(lo, w)).words
                     if out_i is None:
                         return out
                     return out.take(out_i)
@@ -647,10 +663,7 @@ class Pems:
                 # Pinned row-major like the device tier's round blocks.
                 return row_major(jax.vmap(one)(rhos, row_major(rw)))
 
-            try:
-                cache[fn] = body
-            except TypeError:      # fn not weakref-able: run uncached
-                pass
+            cache[key] = body
 
         return lambda rho0, rw: body(rho0, rw, in_j, out_j)
 

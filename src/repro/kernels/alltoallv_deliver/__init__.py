@@ -4,6 +4,9 @@ from .ops import (
     check_fill_range,
     deliver,
     deliver_fused,
+    deliver_packed,
+    pack_slices,
+    stage_slices,
     uses_pallas,
 )
 
@@ -13,6 +16,9 @@ __all__ = [
     "check_fill_range",
     "deliver",
     "deliver_fused",
+    "deliver_packed",
     "deliver_tiles",
+    "pack_slices",
+    "stage_slices",
     "uses_pallas",
 ]

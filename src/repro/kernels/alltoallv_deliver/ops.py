@@ -16,6 +16,15 @@ default rather than an opt-in:
 ``use_kernel=False`` bypasses the kernel entirely (pure-jnp reference),
 which is what the seed implementation did; it is kept so equivalence can be
 asserted end-to-end (``psrs_sort(..., use_kernel=...)``).
+
+The packed Alltoallv (MPI's displacement form: each sender's messages are
+contiguous slices of one field, each receiver's arrive concatenated into
+one field) reaches the kernel through transient tiles:
+:func:`stage_slices` cuts every ``(sender, destination)`` slice into a
+``width``-lane tile, the kernel delivers and masks the tiles, and
+:func:`pack_slices` lays each receiver's tiles end to end.  Neither side
+keeps a ``[v, ω]`` slab: the tiles live for the one delivery.
+:func:`deliver_packed` is the three in a row, the single-process form.
 """
 
 from __future__ import annotations
@@ -26,6 +35,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from .alltoallv_deliver import assemble_proc_tiles, deliver_tiles
 
@@ -168,3 +178,79 @@ def assemble_proc_fused(
         None if fill is None else counts.astype(jnp.int32),
         counts_payload, fill=fill,
     )
+
+
+def stage_slices(words: jnp.ndarray, offsets: jnp.ndarray,
+                 width: int) -> jnp.ndarray:
+    """Sender side of the packed delivery: ``words [m, n]`` holds each
+    sender's messages as contiguous slices, message ``d`` of sender ``s``
+    at ``[offsets[s, d], offsets[s, d + 1])`` (``offsets [m, v + 1]``).
+    Returns the tiles ``[m, v, width]``: tile ``[s, d]`` starts at the
+    message's first word.  Lanes past the message length hold whatever
+    follows it; the delivery masks them.  ``width`` must bound every
+    message.  One dynamic slice per tile with scalar starts, in a loop, so
+    every copy is a contiguous block move."""
+    m, n = words.shape
+    v = offsets.shape[1] - 1
+    # A tail of width lanes keeps every slice in bounds (offsets <= n), so
+    # no start is clamped.
+    ext = jnp.concatenate([words, jnp.zeros((m, width), words.dtype)], axis=1)
+
+    def tile(i, tiles):
+        s, d = i // v, i % v
+        blk = lax.dynamic_slice(ext, (s, offsets[s, d]), (1, width))
+        return lax.dynamic_update_slice(tiles, blk[None], (s, d, 0))
+
+    return lax.fori_loop(0, m * v, tile,
+                         jnp.zeros((m, v, width), words.dtype))
+
+
+def pack_slices(tiles: jnp.ndarray, counts: jnp.ndarray, size: int,
+                fill) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Receiver side of the packed delivery: receiver ``d``'s delivered
+    tiles ``tiles[d, s]`` (valid in their first ``counts [m, v]`` lanes,
+    ``fill`` past them, as the masked delivery leaves them) laid end to
+    end in source order.  Returns ``(packed [m, size], offsets [m, v + 1])``
+    with source ``s``'s words at ``[offsets[d, s], offsets[d, s + 1])`` and
+    ``fill`` past ``offsets[d, v]``.  Words past ``size`` are dropped; the
+    offsets still count them, so a caller sees the overflow."""
+    m, v, width = tiles.shape
+    zero = jnp.zeros((m, 1), jnp.int32)
+    offsets = jnp.concatenate(
+        [zero, jnp.cumsum(counts.astype(jnp.int32), axis=1)], axis=1)
+    start = jnp.minimum(offsets, size)
+    # Tile s is written whole, its fill tail over where tiles s+1.. land
+    # next; writing in source order leaves each word to its own tile.
+    buf = jnp.full((m, size + width), fill, tiles.dtype)
+
+    def place(i, buf):
+        d, s = i // v, i % v
+        blk = lax.dynamic_slice(tiles, (d, s, 0), (1, 1, width))[0]
+        return lax.dynamic_update_slice(buf, blk, (d, start[d, s]))
+
+    buf = lax.fori_loop(0, m * v, place, buf)
+    return buf[:, :size], offsets
+
+
+def deliver_packed(
+    words: jnp.ndarray,                       # [v, n] each sender's slices
+    offsets: jnp.ndarray,                     # [v, v + 1] slice offsets
+    size: int,                                # words of each receive field
+    *,
+    fill,
+    interpret: Optional[bool] = None,
+    use_kernel: bool = True,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The packed Alltoallv among ``v`` contexts of one process: receiver
+    ``d`` gets ``words[s, offsets[s, d]:offsets[s, d + 1]]`` for ``s = 0,
+    1, ...`` end to end.  Returns ``(packed [v, size], recv_offsets [v, v
+    + 1])`` as :func:`pack_slices` does.  The tiles go through the
+    delivery kernel (:func:`deliver_fused`, masked with ``fill``), with the
+    same backend dispatch."""
+    check_fill_range(fill, words.dtype)
+    width = min(words.shape[1], size)       # bounds every message that lands
+    counts = (offsets[:, 1:] - offsets[:, :-1]).astype(jnp.int32)
+    tiles = stage_slices(words, offsets, width)
+    out, _ = deliver_fused(tiles, counts, None, fill=fill,
+                           interpret=interpret, use_kernel=use_kernel)
+    return pack_slices(out, counts.T, size, fill)

@@ -1,8 +1,10 @@
-"""Oracles for direct delivery: masked transpose (+ fused counts)."""
+"""Oracles for direct delivery: masked transpose (+ fused counts), and the
+packed Alltoallv in plain numpy."""
 
 from typing import Optional, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 
 def deliver_ref(msgs: jnp.ndarray, counts: jnp.ndarray, *, fill=0) -> jnp.ndarray:
@@ -53,3 +55,21 @@ def assemble_proc_ref(
     if counts_payload is not None:
         ct = jnp.moveaxis(counts_payload, 0, 2)
     return out, ct
+
+
+def deliver_packed_ref(words: np.ndarray, offsets: np.ndarray, size: int,
+                       fill) -> Tuple[np.ndarray, np.ndarray]:
+    """Oracle for :func:`..ops.deliver_packed`, in numpy: receiver ``d``'s
+    row is ``words[s, offsets[s, d]:offsets[s, d + 1]]`` for every sender
+    ``s`` in order, cut at ``size`` words and ``fill``-padded; its offsets
+    are ``0`` and the running sums of what each sender sent it."""
+    words, offsets = np.asarray(words), np.asarray(offsets)
+    v = offsets.shape[0]
+    packed = np.full((v, size), fill, words.dtype)
+    recv_offsets = np.zeros((v, v + 1), np.int32)
+    for d in range(v):
+        row = np.concatenate([words[s, offsets[s, d]:offsets[s, d + 1]]
+                              for s in range(v)])
+        packed[d, :min(row.size, size)] = row[:size]
+        recv_offsets[d, 1:] = np.cumsum(offsets[:, d + 1] - offsets[:, d])
+    return packed, recv_offsets

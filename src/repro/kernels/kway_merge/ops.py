@@ -167,6 +167,25 @@ def _exact_starts(rows_u32: jnp.ndarray, ranks: jnp.ndarray) -> jnp.ndarray:
     return (lo + take).T                                    # [R, v]
 
 
+def unpack_runs(packed: jnp.ndarray, offsets: jnp.ndarray,
+                width: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The merge's input from a packed receive field: ``packed [size]``
+    holds ``v`` ascending runs end to end, run ``j`` at ``[offsets[j],
+    offsets[j + 1])`` (``offsets [v + 1]``; words past ``size`` were never
+    stored).  Returns ``(runs [v, width], counts [v])``: row ``j`` starts
+    with run ``j``, its lanes past ``counts[j]`` hold what follows it, which
+    :func:`kway_merge` masks.  ``width`` must bound every run.  The rows
+    are transient, one dynamic slice each: nothing of this shape is
+    stored."""
+    size = packed.shape[0]
+    start = jnp.minimum(offsets[:-1], size)
+    counts = jnp.minimum(jnp.minimum(offsets[1:], size) - start, width)
+    ext = jnp.concatenate([packed, jnp.zeros((width,), packed.dtype)])
+    runs = jax.vmap(lambda s0: jax.lax.dynamic_slice(ext, (s0,), (width,))
+                    )(start)
+    return runs, counts.astype(jnp.int32)
+
+
 def kway_merge(
     buckets: jnp.ndarray,                     # [v, cap]; row j ascending in
                                               # its first counts[j] lanes

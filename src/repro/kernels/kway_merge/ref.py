@@ -8,13 +8,15 @@ computed with ``jnp.sort(recv.reshape(-1))[:rcap]`` on fill-masked buckets.
 
 ``exact_starts_ref`` is the splitter search's oracle: the same value-domain
 search as ``ops._exact_starts``, each count a scalar binary search
-(``jnp.searchsorted``) instead of the fence index.
+(``jnp.searchsorted``) instead of the fence index.  ``unpack_runs_ref`` is
+the merge input's oracle, in numpy: the runs of a packed receive field.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def kway_merge_ref(buckets: jnp.ndarray, counts: jnp.ndarray, *,
@@ -54,3 +56,18 @@ def exact_starts_ref(rows_u32: jnp.ndarray, ranks: jnp.ndarray) -> jnp.ndarray:
     need = ranks[None, :] - lo.sum(axis=0, keepdims=True)
     cum = jnp.cumsum(dups, axis=0) - dups
     return (lo + jnp.clip(need - cum, 0, dups)).T
+
+
+def unpack_runs_ref(packed: np.ndarray, offsets: np.ndarray,
+                    fill) -> np.ndarray:
+    """Oracle for :func:`..ops.unpack_runs`, in numpy: run ``j`` of the
+    packed field (``packed[offsets[j]:offsets[j + 1]]``, cut at the field's
+    end) as row ``j`` of a ``[v, max run]`` array, ``fill`` past it."""
+    packed, offsets = np.asarray(packed), np.asarray(offsets)
+    ends = np.minimum(offsets, packed.size)
+    runs = [packed[ends[j]:ends[j + 1]] for j in range(offsets.size - 1)]
+    out = np.full((len(runs), max(1, max(r.size for r in runs))), fill,
+                  packed.dtype)
+    for j, r in enumerate(runs):
+        out[j, :r.size] = r
+    return out

@@ -5,7 +5,7 @@ Four virtual supersteps, exactly the thesis' structure:
   1. local sort + choose v regular samples        (computation)
   2. **Gather** all v² samples at the root
   3. root sorts samples, picks v−1 splitters; **Bcast**
-  4. partition local data by splitters; **Alltoallv** counts + buckets
+  4. partition the sorted local data by splitters; **Alltoallv** the buckets
   5. merge received buckets                        (computation)
 
 The final Alltoallv moves the entire data set — it dominates I/O, which is
@@ -13,10 +13,18 @@ why PSRS is the thesis' flagship benchmark for direct vs indirect delivery.
 
 Duplicate keys are handled by lexicographic (value, global-index) splitters,
 which preserves the 2n/v per-receiver bound even for constant inputs.
+
+The context is packed, about 5 words a key: the sorted ``data`` (n/v
+words), whose buckets are contiguous slices described by ``v + 1`` offsets
+(``boff``), the received buckets end to end in ``brecv`` (2n/v words, the
+receive bound) with their offsets (``roff``), the merged ``result`` (2n/v),
+and the O(v²) samples and splitters.  No field is sized per destination:
+the Alltoallv is the packed form of :meth:`repro.core.Pems.alltoallv`.
 """
 
 from __future__ import annotations
 
+import functools
 import os
 import signal
 import time
@@ -29,8 +37,8 @@ import numpy as np
 from repro.core import (ContextLayout, Pems, PemsConfig, SuperstepCursor,
                         atomic_replace_file)
 from repro.kernels.bitonic_sort import bitonic_sort
-from repro.kernels.kway_merge import kway_merge
-from .common import INT_MAX, group_by_dest
+from repro.kernels.kway_merge import kway_merge, unpack_runs
+from .common import INT_MAX
 
 # Fields each stage both reads and writes: rerunning such a stage after a
 # mid-stage crash would compute from possibly-torn rows, so the recoverable
@@ -40,64 +48,19 @@ from .common import INT_MAX, group_by_dest
 STAGE_SNAPSHOT_FIELDS = {
     "sort_sample": ("data",),
     "bcast_splitters": ("gsplit",),
-    "merge": ("oflow",),
 }
 
 
-def _build(v: int, k: int, n_v: int, cap, rcap, driver: str,
-           mode: str, local_sort, use_kernel: bool = True,
-           tier: str = "device", backing_path=None, device_cap_bytes=None,
-           P: int = 1, mesh=None, alpha=None,
-           io_driver=None, io_queue_depth=None,
-           fault_spec=None, checksums: bool = False, io_retries=None,
-           merge_kernel=None, merge_tile=None,
-           trace: bool = False, trace_path=None):
-    # One home for the PSRS capacity defaults: the always-safe per-message
-    # bound n/v and the 2n/v per-receiver guarantee.
-    cap = n_v if cap is None else cap
-    rcap = 2 * n_v if rcap is None else rcap
-    # Default local sort: the bitonic kernel (auto backend — compiled Pallas
-    # on TPU, jnp.sort on CPU/GPU).  use_kernel=False keeps the seed's
-    # jnp.sort on every path; both are bit-identical on int32 keys.
-    if local_sort is None:
-        local_sort = bitonic_sort if use_kernel else jnp.sort
-    lo = (
-        ContextLayout()
-        .add("data", (n_v,), jnp.int32)
-        .add("samp", (v, 2), jnp.int32)        # (value, global index)
-        .add("allsamp", (v, v, 2), jnp.int32)
-        .add("gsplit", (v, 2), jnp.int32)
-        .add("bsend", (v, cap), jnp.int32)
-        .add("bscnt", (v,), jnp.int32)
-        .add("brecv", (v, cap), jnp.int32)
-        .add("brcnt", (v,), jnp.int32)
-        .add("result", (rcap,), jnp.int32)
-        .add("rcount", (1,), jnp.int32)
-        .add("oflow", (1,), jnp.int32)
-    )
-    io_kw = {}
-    if io_driver is not None:
-        io_kw["io_driver"] = io_driver
-    if io_queue_depth is not None:
-        io_kw["io_queue_depth"] = io_queue_depth
-    if fault_spec is not None:
-        io_kw["fault_spec"] = fault_spec
-    if io_retries is not None:
-        io_kw["io_retries"] = io_retries
-    if checksums:
-        io_kw["checksums"] = True
-    if merge_kernel is not None:
-        io_kw["merge_kernel"] = bool(merge_kernel)
-    if merge_tile is not None:
-        io_kw["merge_tile"] = merge_tile
-    if trace:
-        io_kw["trace"] = True
-    if trace_path is not None:
-        io_kw["trace_path"] = trace_path
-    pems = Pems(PemsConfig(v=v, k=k, P=P, driver=driver, tier=tier,
-                           backing_path=backing_path, alpha=alpha,
-                           device_cap_bytes=device_cap_bytes, **io_kw),
-                lo, mesh=mesh)
+@functools.lru_cache(maxsize=32)
+def _stage_fns(v: int, n_v: int, rcap: int, local_sort, merge_tile):
+    """The four compute stages' bodies for one static configuration, built
+    once: a later job of the same shape hands the executor the same
+    functions, so the tiered tiers reuse their jitted round bodies instead
+    of tracing and lowering them again.  ``merge_tile=None`` merges by the
+    dense re-sort."""
+    # One sender's bucket is at most its n/v keys, so a run of the merge
+    # needs no more than n/v lanes.
+    run_width = min(n_v, rcap)
 
     # Each stage body runs under a named scope (psrs.<stage>), so its
     # device operations carry the stage's name in the profiler's trace, in
@@ -127,47 +90,98 @@ def _build(v: int, k: int, n_v: int, cap, rcap, driver: str,
 
     @jax.named_scope("psrs.partition")
     def partition(rho, ctx):
-        data = ctx.get("data")
+        data = ctx.get("data")                 # sorted by sort_sample
         gs = ctx.get("gsplit")
-        gid = rho * n_v + jnp.arange(n_v, dtype=jnp.int32)
         sv, sg = gs[:-1, 0], gs[:-1, 1]        # v−1 splitters
-        # dest = #splitters (sv, sg) <= (x, gid) lexicographically.
-        le = (sv[None, :] < data[:, None]) | (
-            (sv[None, :] == data[:, None]) & (sg[None, :] <= gid[:, None])
-        )
-        dest = le.sum(axis=1).astype(jnp.int32)
-        msgs, counts, _, ok = group_by_dest(data, dest, v, cap, fill=INT_MAX)
-        return (
-            ctx.set("bsend", msgs)
-            .set("bscnt", counts)
-            .set("oflow", (~ok).astype(jnp.int32)[None])
-        )
+        # A key goes to bucket d = #splitters (sv, sg) <= (x, gid), with
+        # gid = rho·n_v + its position.  Along the sorted data (x, gid)
+        # ascends, so bucket d is a slice and its end is #{(x, gid) <
+        # splitter d}: the keys below sv, then those equal to it whose
+        # position is below sg − rho·n_v.
+        with jax.named_scope("psrs.bucket_offsets"):
+            lt = jnp.searchsorted(data, sv, side="left")    # #{x < sv}
+            le = jnp.searchsorted(data, sv, side="right")   # #{x <= sv}
+            cut = jnp.clip(sg - rho * n_v, lt, le).astype(jnp.int32)
+            boff = jnp.concatenate([jnp.zeros((1,), jnp.int32), cut,
+                                    jnp.full((1,), n_v, jnp.int32)])
+        return ctx.set("boff", boff)
 
     @jax.named_scope("psrs.merge")
     def merge(rho, ctx):
-        # The boundary mask is fused into delivery (alltoallv fill=INT_MAX):
-        # lanes past brcnt arrive as INT_MAX, so the received buckets merge
-        # as-is — no re-mask pass over the 2n/v received words.
-        recv = ctx.get("brecv")              # [v, cap]
-        cnt = ctx.get("brcnt")               # [v]
-        if pems.cfg.merge_kernel and use_kernel:
+        # The received buckets lie end to end in brecv; roff[v] counts them
+        # all, so a total past rcap (the overflow extract reports) is never
+        # read past the field.
+        recv = ctx.get("brecv")              # [rcap]
+        roff = ctx.get("roff")               # [v + 1]
+        if merge_tile is not None:
             # Tiled k-way merge with exact splitting: O(n log v) over the
-            # already-sorted buckets instead of the O(n log n) re-sort, and
-            # the overflow flag is raised by the op itself at the stage
-            # boundary — the truncation to rcap can never outrun it.
-            merged, total, over = kway_merge(
-                recv, cnt, rcap=rcap, tile=pems.cfg.merge_tile,
-                fill=INT_MAX)
+            # already-sorted buckets instead of the O(n log n) re-sort, on
+            # runs cut from brecv for the merge alone.
+            runs, cnt = unpack_runs(recv, roff, run_width)
+            merged, _, _ = kway_merge(runs, cnt, rcap=rcap, tile=merge_tile,
+                                      fill=INT_MAX)
         else:
-            flat = recv.reshape(-1)
-            merged = local_sort(flat)[:rcap]
-            total = cnt.sum()
-            over = (total > rcap).astype(jnp.int32)
-        return (
-            ctx.set("result", merged)
-            .set("rcount", total[None].astype(jnp.int32))
-            .set("oflow", ctx.get("oflow") | over.astype(jnp.int32)[None])
-        )
+            lane = jnp.arange(rcap, dtype=jnp.int32)
+            merged = local_sort(jnp.where(lane < roff[v], recv, INT_MAX))
+        return ctx.set("result", merged)
+
+    return sort_and_sample, pick_splitters, partition, merge
+
+
+def _build(v: int, k: int, n_v: int, rcap, driver: str,
+           mode: str, local_sort, use_kernel: bool = True,
+           tier: str = "device", backing_path=None, device_cap_bytes=None,
+           P: int = 1, mesh=None, alpha=None,
+           io_driver=None, io_queue_depth=None,
+           fault_spec=None, checksums: bool = False, io_retries=None,
+           merge_kernel=None, merge_tile=None,
+           trace: bool = False, trace_path=None):
+    # The 2n/v per-receiver guarantee.
+    rcap = 2 * n_v if rcap is None else rcap
+    # Default local sort: the bitonic kernel (auto backend — compiled Pallas
+    # on TPU, jnp.sort on CPU/GPU).  use_kernel=False keeps the seed's
+    # jnp.sort on every path; both are bit-identical on int32 keys.
+    if local_sort is None:
+        local_sort = bitonic_sort if use_kernel else jnp.sort
+    lo = (
+        ContextLayout()
+        .add("data", (n_v,), jnp.int32)
+        .add("samp", (v, 2), jnp.int32)        # (value, global index)
+        .add("allsamp", (v, v, 2), jnp.int32)
+        .add("gsplit", (v, 2), jnp.int32)
+        .add("boff", (v + 1,), jnp.int32)      # bucket d: data[boff[d]:boff[d+1]]
+        .add("brecv", (rcap,), jnp.int32)      # received buckets, end to end
+        .add("roff", (v + 1,), jnp.int32)      # roff[v]: the total received
+        .add("result", (rcap,), jnp.int32)
+    )
+    io_kw = {}
+    if io_driver is not None:
+        io_kw["io_driver"] = io_driver
+    if io_queue_depth is not None:
+        io_kw["io_queue_depth"] = io_queue_depth
+    if fault_spec is not None:
+        io_kw["fault_spec"] = fault_spec
+    if io_retries is not None:
+        io_kw["io_retries"] = io_retries
+    if checksums:
+        io_kw["checksums"] = True
+    if merge_kernel is not None:
+        io_kw["merge_kernel"] = bool(merge_kernel)
+    if merge_tile is not None:
+        io_kw["merge_tile"] = merge_tile
+    if trace:
+        io_kw["trace"] = True
+    if trace_path is not None:
+        io_kw["trace_path"] = trace_path
+    pems = Pems(PemsConfig(v=v, k=k, P=P, driver=driver, tier=tier,
+                           backing_path=backing_path, alpha=alpha,
+                           device_cap_bytes=device_cap_bytes, **io_kw),
+                lo, mesh=mesh)
+
+    sort_and_sample, pick_splitters, partition, merge = _stage_fns(
+        v, n_v, rcap, local_sort,
+        pems.cfg.merge_tile if pems.cfg.merge_kernel and use_kernel
+        else None)
 
     # The program as an explicit stage list: the device tier jit-fuses the
     # whole pipeline as before, while backing tiers run it stage-by-stage
@@ -188,18 +202,17 @@ def _build(v: int, k: int, n_v: int, cap, rcap, driver: str,
         ("bcast_splitters", lambda st, procs=None: pems.bcast(
             st, "gsplit", root=0, procs=procs)),
         ("partition", lambda st, procs=None: pems.superstep(
-            st, partition, reads=["data", "gsplit"],
-            writes=["bsend", "bscnt", "oflow"], procs=procs)),
+            st, partition, reads=["data", "gsplit"], writes=["boff"],
+            procs=procs)),
         ("alltoallv", lambda st, procs=None: pems.alltoallv(
-            st, "bsend", "brecv", "bscnt", "brcnt",
+            st, "data", "brecv", offsets=("boff", "roff"),
             mode=mode, fill=INT_MAX, use_kernel=use_kernel, procs=procs)),
         # stream=True: on a disk backing the merge's bucket reads are
         # prefetched through the block API while the previous round merges,
         # under every driver (TierStats.merge_prefetch_events counts them).
         ("merge", lambda st, procs=None: pems.superstep(
-            st, merge, reads=["brecv", "brcnt", "oflow"],
-            writes=["result", "rcount", "oflow"], procs=procs,
-            stream=True)),
+            st, merge, reads=["brecv", "roff"], writes=["result"],
+            procs=procs, stream=True)),
     ]
 
     # Stage spans on the main tracer's "stages" lane, for the host-driven
@@ -225,8 +238,9 @@ def _build(v: int, k: int, n_v: int, cap, rcap, driver: str,
         return store.with_field("data", data_blocks)
 
     def extract(store):
-        return (store.field("result"), store.field("rcount"),
-                store.field("oflow"))
+        # Each context's received total, [v, 1], and whether it overflowed.
+        total = store.field("roff")[:, v:]
+        return store.field("result"), total, total > rcap
 
     # The single-process device tier jit-fuses the whole pipeline, traced
     # or not, running the bare steps; the P > 1 mesh path runs the staged
@@ -251,7 +265,6 @@ def psrs_plan(
     k: int = 1,
     driver: str = "explicit",
     mode: str = "direct",
-    cap: Optional[int] = None,
     rcap: Optional[int] = None,
     local_sort=None,
     use_kernel: bool = True,
@@ -285,7 +298,7 @@ def psrs_plan(
     / :func:`psrs_run_recoverable` then export automatically).
     """
     pems, _, (load, steps, extract) = _build(
-        v, k, n_v, cap, rcap, driver, mode, local_sort,
+        v, k, n_v, rcap, driver, mode, local_sort,
         use_kernel=use_kernel, tier=tier, backing_path=backing_path,
         device_cap_bytes=device_cap_bytes, P=P, mesh=mesh, alpha=alpha,
         io_driver=io_driver, io_queue_depth=io_queue_depth,
@@ -302,7 +315,6 @@ def psrs_sort(
     k: int = 1,
     driver: str = "explicit",
     mode: str = "direct",
-    cap: Optional[int] = None,
     rcap: Optional[int] = None,
     local_sort=None,
     return_pems: bool = False,
@@ -326,13 +338,13 @@ def psrs_sort(
     """Sort int32 ``keys`` ([n], n divisible by v) with PSRS on PEMS.
 
     ``mode`` selects PEMS2 direct delivery or the PEMS1 indirect baseline for
-    the final Alltoallv; ``cap`` is the per-(sender,dest) message capacity ω
-    (defaults to the always-safe n/v) and ``rcap`` the per-receiver capacity
-    (defaults to the PSRS guarantee 2n/v).  ``use_kernel`` toggles the
-    kernel paths end to end — the fused Pallas delivery in the final
-    Alltoallv, the bitonic local sort, and the tiled k-way merge; ``False``
-    keeps the seed's dense/jnp.sort routes (results are bit-identical
-    either way; kept for equivalence testing).  ``merge_kernel``/
+    the final Alltoallv; ``rcap`` is the per-receiver capacity (defaults to
+    the PSRS guarantee 2n/v).  The context is packed (about 5 words a key,
+    see the module docstring), so messages need no capacity of their own.
+    ``use_kernel`` toggles the kernel paths end to end — the fused Pallas
+    delivery in the final Alltoallv, the bitonic local sort, and the tiled
+    k-way merge; ``False`` keeps the seed's dense/jnp.sort routes (results
+    are bit-identical either way; kept for equivalence testing).  ``merge_kernel``/
     ``merge_tile`` (defaults from :class:`~repro.core.PemsConfig`) control
     the merge stage alone: the exact-splitter tiled merge of the v received
     sorted buckets — O(n log v) instead of the dense O(n log n) re-sort —
@@ -385,7 +397,7 @@ def psrs_sort(
 
     Raises ``ValueError`` for n not divisible by v (and for any invalid
     :class:`~repro.core.PemsConfig` combination) and ``OverflowError``
-    when a bucket exceeds ``cap``/``rcap``.
+    when a context receives more than ``rcap`` keys.
     """
     # call:prepare builds the tracer it is recorded in, so it is timed and
     # annotated by hand; the other call spans are ordinary spans.
@@ -397,7 +409,7 @@ def psrs_sort(
             raise ValueError(f"n={n} must be divisible by v={v}")
         n_v = n // v
         pems, program, _ = _build(
-            v, k, n_v, cap, rcap, driver, mode, local_sort,
+            v, k, n_v, rcap, driver, mode, local_sort,
             use_kernel=use_kernel, tier=tier, backing_path=backing_path,
             device_cap_bytes=device_cap_bytes, P=P, mesh=mesh, alpha=alpha,
             io_driver=io_driver, io_queue_depth=io_queue_depth,
@@ -423,9 +435,7 @@ def psrs_sort(
         pems.export_trace()
     if overflow:
         raise OverflowError(
-            "PSRS message capacity exceeded; raise cap/rcap "
-            f"(cap={cap}, rcap={rcap})"
-        )
+            f"PSRS receive capacity exceeded; raise rcap (rcap={rcap})")
     if return_pems:
         return out, pems
     return out
@@ -472,7 +482,6 @@ def psrs_run_recoverable(
     alpha: Optional[int] = None,
     driver: str = "explicit",
     mode: str = "direct",
-    cap: Optional[int] = None,
     rcap: Optional[int] = None,
     local_sort=None,
     use_kernel: bool = True,
@@ -526,7 +535,7 @@ def psrs_run_recoverable(
     the chaos-test hooks.
 
     Raises ``ValueError`` for a non-disk ``tier`` or n not divisible by v,
-    and ``OverflowError`` when a bucket exceeds ``cap``/``rcap``.
+    and ``OverflowError`` when a context receives more than ``rcap`` keys.
     """
     keys = np.asarray(keys, np.int32)
     n = keys.size
@@ -540,7 +549,7 @@ def psrs_run_recoverable(
     backing_path = os.path.join(state_dir, "ctx.bin")
     pems, _load_unused, steps, extract = psrs_plan(
         v, n_v, k=k, P=P, alpha=alpha, driver=driver, mode=mode,
-        cap=cap, rcap=rcap,
+        rcap=rcap,
         local_sort=local_sort, use_kernel=use_kernel, tier=tier,
         backing_path=backing_path, device_cap_bytes=device_cap_bytes,
         io_driver=io_driver, io_queue_depth=io_queue_depth,
@@ -643,9 +652,7 @@ def psrs_run_recoverable(
     rcount = np.asarray(rcount)[:, 0]
     if np.asarray(oflow).any():
         raise OverflowError(
-            "PSRS message capacity exceeded; raise cap/rcap "
-            f"(cap={cap}, rcap={rcap})"
-        )
+            f"PSRS receive capacity exceeded; raise rcap (rcap={rcap})")
     out = np.concatenate([result[i, : rcount[i]] for i in range(v)])
     if return_pems:
         return out, pems

@@ -15,9 +15,10 @@ from repro.pems_apps import psrs, psrs_sort
 
 _N, _V, _K = 2048, 8, 2
 SCOPES = ("psrs.sort_sample", "psrs.local_sort", "psrs.pick_splitters",
-          "psrs.partition", "psrs.merge", "pems.gather", "pems.bcast",
-          "pems.alltoallv", "kway_merge.splitters", "kway_merge.index",
-          "kway_merge.gather", "kway_merge.tiles")
+          "psrs.partition", "psrs.bucket_offsets", "psrs.merge",
+          "pems.gather", "pems.bcast", "pems.alltoallv",
+          "kway_merge.splitters", "kway_merge.index", "kway_merge.gather",
+          "kway_merge.tiles")
 
 
 def _keys(seed=5):
@@ -26,7 +27,7 @@ def _keys(seed=5):
 
 
 def _program(trace: bool):
-    _, program, _ = psrs._build(_V, _K, _N // _V, None, None, "explicit",
+    _, program, _ = psrs._build(_V, _K, _N // _V, None, "explicit",
                                 "direct", None, trace=trace)
     return program
 

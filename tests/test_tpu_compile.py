@@ -4,7 +4,9 @@ The TPU compiler is installed with JAX, and it compiles for a chip that is
 described, not attached: these tests need no TPU.  Each kernel is compiled
 at the shapes the executor hands it on the main path (``chip_smoke.py``'s
 device tier: v=16, k=4, n=2^25; its mesh phase for the (src_proc,
-dst_proc)-tiled staging) and must come out as a ``tpu_custom_call`` — a
+dst_proc)-tiled staging; the benchmark's NPB IS class A cells, n=2^23, for
+the packed context's delivery and merge) and must come out as a
+``tpu_custom_call`` — a
 kernel refused by Mosaic, or lowered to something else, fails here instead
 of on the chip.  Nothing runs, so results are checked elsewhere (the
 interpret-mode tests in ``test_kernels.py`` and the chip smoke run).
@@ -18,10 +20,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.alltoallv_deliver import assemble_proc_tiles, deliver_tiles
+from repro.kernels.alltoallv_deliver import (assemble_proc_tiles,
+                                             deliver_packed, deliver_tiles)
 from repro.kernels.bitonic_sort.bitonic_sort import bitonic_sort_rows
 from repro.kernels.bitonic_sort.ops import KERNEL_MAX_N
-from repro.kernels.kway_merge import kway_merge, merge_tile_grid
+from repro.kernels.kway_merge import kway_merge, merge_tile_grid, unpack_runs
 
 INT_MAX = 2**31 - 1
 V, K = 16, 4
@@ -120,6 +123,35 @@ def test_kway_merge_compiles(one_chip):
     merge = jax.vmap(lambda b, c: kway_merge(b, c, rcap=2 * cap, tile=256,
                                              fill=INT_MAX, interpret=False))
     _assert_kernel(_compile_text(merge, B, C), "kway_merge")
+
+
+def test_packed_delivery_compiles(one_chip):
+    """The packed Alltoallv of the class-A cells (v=16, 2^19 sorted keys
+    per context, a 2^20-word receive field): the staged tiles go through
+    the delivery kernel (``interpret=False``: the chip's dispatch)."""
+    n_v = (1 << 23) // V
+    W = jax.ShapeDtypeStruct((V, n_v), jnp.int32, sharding=one_chip)
+    O = jax.ShapeDtypeStruct((V, V + 1), jnp.int32, sharding=one_chip)
+    fn = lambda w, o: deliver_packed(w, o, 2 * n_v, fill=INT_MAX,
+                                     interpret=False)
+    _assert_kernel(_compile_text(fn, W, O), "alltoallv_deliver")
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_packed_merge_compiles(one_chip, k):
+    """The merge stage of the class-A cells from the packed receive field:
+    the runs unpacked in front of the k-way merge, vmapped over the k
+    resident contexts (k=4 on the device cell, k=1 on the file cell)."""
+    n_v = (1 << 23) // V
+    R = jax.ShapeDtypeStruct((k, 2 * n_v), jnp.int32, sharding=one_chip)
+    O = jax.ShapeDtypeStruct((k, V + 1), jnp.int32, sharding=one_chip)
+
+    def merge(recv, offs):
+        runs, counts = unpack_runs(recv, offs, n_v)
+        return kway_merge(runs, counts, rcap=2 * n_v, tile=256,
+                          fill=INT_MAX, interpret=False)[0]
+
+    _assert_kernel(_compile_text(jax.vmap(merge), R, O), "kway_merge")
 
 
 @pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
