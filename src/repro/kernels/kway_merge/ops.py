@@ -25,11 +25,25 @@ Pipeline:
    and a dense compare inside it — for ``cap = 2^19`` two block gathers
    where a scalar binary search took 20 dependent scalar gathers.
 3. **Compact gather**: tile ``g``'s window lengths sum to exactly ``tile``
-   across the buckets, so the windows concatenate (in bucket order, via an
-   owner-bucket ``searchsorted`` over the exclusive length prefix) into one
+   across the buckets, so the windows concatenate in bucket order into one
    dense ``tile``-wide row — ``tiles[G, tile]``, each row a permutation of
-   its tile's elements.  No per-bucket padding: gather traffic equals
-   output size.
+   its tile's elements.  Window ``(g, j)`` is a contiguous run: it starts
+   at ``starts[g, j]``, has length ``lens[g, j]`` and lands at lane
+   ``cum[g, j]`` (the exclusive length prefix), so slot ``s`` of the row is
+   lane ``s + starts[g, j] − cum[g, j]`` of bucket ``j`` for the ``j``
+   whose ``[cum, cum + lens)`` holds ``s``.  The index arithmetic is per
+   ``(g, j)`` and no lane is gathered alone: each window is read as the
+   aligned 128-lane blocks of the fence index's first level that cover its
+   source span (a row gather of whole blocks, as the splitter search reads
+   them), shifted into place by a log-step lane shifter (a static shift
+   and a select per bit of the offset within the block), masked to
+   ``[cum, cum + lens)`` and summed over the ``v`` buckets — each slot has
+   one owner, so the sum is exact.  The ``[·, v, tile]`` windows exist one
+   chunk of ``⌈G/v⌉`` tiles at a time (``lax.map``): transient memory
+   stays ``O(rcap)`` words.  On CPU/GPU (the dispatch below) the lane
+   gather of ``ref.compact_tiles_ref`` stays (each slot's owner by a
+   ``searchsorted`` over the window prefix, then one gather per lane):
+   there the shifter's passes cost more than the gather.
 4. **Tile merge** — a bitonic sorting network over each row, as the Pallas
    grid or one batched jnp expression, backend dispatched like every other
    kernel here; a tile wider than the bitonic kernel's
@@ -85,19 +99,20 @@ _LANES = 128                                  # fence fan-out: the TPU lane widt
 _U32_MAX = 0xFFFFFFFF
 
 
+@jax.named_scope("kway_merge.index")
 def _fence_index(rows_u32: jnp.ndarray):
     """128-ary fence index over ``v`` ascending uint32 rows ``[v, cap]``.
 
     Returns ``(top [v, ≤128], levels)``: ``levels[0]`` is the row itself
-    viewed as ``[v, nb, 128]`` blocks, and each ``levels[i + 1]`` holds the
-    first elements (fences) of ``levels[i]``'s blocks, again as 128-lane
-    blocks; ``top`` is the fences of the last level (the row itself when
-    ``cap ≤ 128``).  Tails are padded with ``0xFFFFFFFF``, which no
-    ``x < q`` ever counts."""
+    viewed as ``[v, nb, 128]`` blocks (also what the compact gather reads
+    its windows from), and each ``levels[i + 1]`` holds the first elements
+    (fences) of ``levels[i]``'s blocks, again as 128-lane blocks; ``top``
+    is the fences of the last level.  Tails are padded with
+    ``0xFFFFFFFF``, which no ``x < q`` ever counts."""
     v = rows_u32.shape[0]
     levels = []
     x = rows_u32
-    while x.shape[1] > _LANES:
+    while not levels or x.shape[1] > _LANES:
         nb = -(-x.shape[1] // _LANES)
         x = jnp.pad(x, ((0, 0), (0, nb * _LANES - x.shape[1])),
                     constant_values=jnp.uint32(_U32_MAX))
@@ -132,21 +147,19 @@ def _count_le(index, q: jnp.ndarray, cap: int) -> jnp.ndarray:
                      _count_lt(index, nxt))
 
 
-@jax.named_scope("kway_merge.splitters")
-def _exact_starts(rows_u32: jnp.ndarray, ranks: jnp.ndarray) -> jnp.ndarray:
-    """Per-bucket window starts for global ``ranks`` over ``v`` ascending
-    uint32 rows: ``starts[r, j]`` with ``Σ_j starts[r, j] = ranks[r]``.
+def _exact_starts(index, ranks: jnp.ndarray, cap: int) -> jnp.ndarray:
+    """Per-bucket window starts for global ``ranks`` over the ``v``
+    ascending uint32 rows ``[v, cap]`` of the fence ``index``:
+    ``starts[r, j]`` with ``Σ_j starts[r, j] = ranks[r]``.
 
     For each rank the MSB-first build finds ``t = max u: #{x < u} < rank``
     (so ``#{x ≤ t} ≥ rank > #{x < t}``); the ``rank − #{x < t}`` duplicates
     of ``t`` are assigned greedily in bucket order, which keeps the starts
     monotone across ranks — consecutive boundaries carve consistent,
-    disjoint windows.  Every count goes through one fence index, built
-    once per call; ``ref.exact_starts_ref`` is the same search by scalar
-    binary search."""
+    disjoint windows.  Every count goes through the one fence index of
+    the call; ``ref.exact_starts_ref`` is the same search by scalar binary
+    search."""
     ranks = ranks.astype(jnp.int32)
-    with jax.named_scope("kway_merge.index"):
-        index = _fence_index(rows_u32)
 
     # lax.fori_loop rather than an unrolled Python loop: the index is a
     # loop-invariant input materialised once, and the trace stays small.
@@ -159,12 +172,66 @@ def _exact_starts(rows_u32: jnp.ndarray, ranks: jnp.ndarray) -> jnp.ndarray:
                           jnp.zeros(ranks.shape, jnp.uint32))
 
     lo = _count_lt(index, u)                  # [v, R] elements < t per bucket
-    hi = _count_le(index, u, rows_u32.shape[1])   # [v, R] elements <= t
+    hi = _count_le(index, u, cap)             # [v, R] elements <= t
     dups = hi - lo
     need = ranks[None, :] - lo.sum(axis=0, keepdims=True)   # duplicates of t
     cum = jnp.cumsum(dups, axis=0) - dups                   # exclusive prefix
     take = jnp.clip(need - cum, 0, dups)
     return (lo + take).T                                    # [R, v]
+
+
+def _from_biased_u32(x: jnp.ndarray, dtype) -> jnp.ndarray:
+    """Inverse of :func:`_to_biased_u32`."""
+    if jnp.dtype(dtype) == jnp.int32:
+        return jax.lax.bitcast_convert_type(x ^ jnp.uint32(0x80000000),
+                                            jnp.int32)
+    return x
+
+
+def _compact_blocks(index, starts: jnp.ndarray, tile: int,
+                    fill) -> jnp.ndarray:
+    """``tiles [G, tile]`` of ``fill``'s dtype: row ``g`` holds the windows
+    ``[starts[g, j], starts[g + 1, j])`` of the fence ``index``'s rows
+    end to end in bucket order, ``fill`` past their total (the last tile,
+    where the ranks stop at ``v·cap``).  Each window is read from the
+    index's 128-lane blocks and placed by a log-step shifter;
+    ``ref.compact_tiles_ref`` is the same compaction lane by lane."""
+    blocks = index[1][0]                                       # [v, nb, 128]
+    v, nb, _ = blocks.shape
+    lens = starts[1:] - starts[:-1]                          # [G, v]
+    cum = jnp.cumsum(lens, axis=1) - lens          # where each window lands
+    G = lens.shape[0]
+    # Slot s of window (g, j) is lane s + q of row j.  The span [q, q +
+    # tile) lies in the nblk blocks from q // 128, shifted left by q % 128;
+    # blocks clamped into the row hold only lanes outside the window.
+    q = starts[:-1] - cum
+    nblk = -(-(tile + _LANES - 1) // _LANES)
+    slot = jnp.arange(tile, dtype=jnp.int32)
+    bucket = jnp.arange(v, dtype=jnp.int32)[:, None]
+
+    def chunk(q, cum, lens):                          # [C, v] each
+        b = jnp.clip(q[..., None] // _LANES
+                     + jnp.arange(nblk, dtype=jnp.int32), 0, nb - 1)
+        x = blocks[bucket, b].reshape(q.shape + (nblk * _LANES,))
+        width = nblk * _LANES
+        shift = (q % _LANES)[..., None]
+        for bit in reversed(range(_LANES.bit_length() - 1)):
+            d = 1 << bit
+            width -= d
+            x = _materialize(jnp.where((shift >> bit) & 1 == 1,
+                                       x[..., d:d + width], x[..., :width]))
+        mine = ((cum[..., None] <= slot)
+                & (slot < (cum + lens)[..., None]))            # [C, v, tile]
+        row = jnp.where(mine, x[..., :tile], 0).sum(axis=1, dtype=x.dtype)
+        return jnp.where(slot < lens.sum(axis=1, keepdims=True),
+                         _from_biased_u32(row, fill.dtype), fill)
+
+    # The [C, v, ·] windows of C = ⌈G/v⌉ tiles at a time: O(G·tile) words.
+    n = min(v, G)
+    C = -(-G // n)
+    parts = [jnp.pad(a, ((0, n * C - G), (0, 0))).reshape(n, C, v)
+             for a in (q, cum, lens)]
+    return jax.lax.map(lambda a: chunk(*a), parts).reshape(n * C, tile)[:G]
 
 
 def unpack_runs(packed: jnp.ndarray, offsets: jnp.ndarray,
@@ -245,28 +312,21 @@ def kway_merge(
         jnp.arange(G + 1, dtype=jnp.int32) * tile, jnp.int32(n_all))
 
     # The three phases' operations carry their names in the profiler's
-    # trace: kway_merge.splitters (_exact_starts), kway_merge.gather and
-    # kway_merge.tiles.
-    rows_u32 = _to_biased_u32(masked)
-    starts = _exact_starts(rows_u32, ranks)   # [G+1, v]
+    # trace: kway_merge.splitters (the fence index and _exact_starts),
+    # kway_merge.gather (the compaction) and kway_merge.tiles.
+    with jax.named_scope("kway_merge.splitters"):
+        index = _fence_index(_to_biased_u32(masked))
+        starts = _exact_starts(index, ranks, cap)          # [G+1, v]
 
-    # Compact gather: tile g's per-bucket window lengths sum to exactly
-    # `tile` (minus the clamp at n_all on the last tile), so the windows
-    # concatenate into one dense [tile] row.  Slot s of tile g belongs to
-    # the bucket whose exclusive length-prefix covers s; a searchsorted
-    # over that prefix finds it without materialising [G, v, tile].
     with jax.named_scope("kway_merge.gather"):
-        lens = starts[1:] - starts[:-1]                        # [G, v]
-        cum = jnp.cumsum(lens, axis=1) - lens                  # excl prefix
-        slot = jnp.arange(tile, dtype=jnp.int32)
-        own = jax.vmap(
-            lambda c: jnp.searchsorted(c, slot, side="right")
-        )(cum).astype(jnp.int32) - 1                           # [G, tile]
-        off = slot[None, :] - jnp.take_along_axis(cum, own, axis=1)
-        valid = off < jnp.take_along_axis(lens, own, axis=1)   # last tile
-        pos = jnp.take_along_axis(starts[:-1], own, axis=1) + off
-        flat = own * cap + jnp.clip(pos, 0, cap - 1)
-        tiles = jnp.where(valid, jnp.take(masked.reshape(-1), flat), fill_v)
+        # Whole blocks on the chip's dispatch, where a lane gather costs a
+        # dependent access per lane; on CPU/GPU the one lane gather is
+        # cheaper than the shifter's passes.
+        if uses_pallas(interpret):
+            tiles = _compact_blocks(index, starts, tile, fill_v)
+        else:
+            from .ref import compact_tiles_ref
+            tiles = compact_tiles_ref(masked, starts, tile, fill_v)
         tiles = _materialize(tiles)           # don't re-fuse into the network
 
     with jax.named_scope("kway_merge.tiles"):

@@ -8,8 +8,10 @@ computed with ``jnp.sort(recv.reshape(-1))[:rcap]`` on fill-masked buckets.
 
 ``exact_starts_ref`` is the splitter search's oracle: the same value-domain
 search as ``ops._exact_starts``, each count a scalar binary search
-(``jnp.searchsorted``) instead of the fence index.  ``unpack_runs_ref`` is
-the merge input's oracle, in numpy: the runs of a packed receive field.
+(``jnp.searchsorted``) instead of the fence index.  ``compact_tiles_ref`` is
+the compact gather's oracle, and its CPU/GPU form: each output slot's owner
+bucket by ``searchsorted`` over the window prefix, and one scalar gather per
+lane.  ``unpack_runs_ref`` is the merge input's oracle, in numpy: the runs of a packed receive field.
 """
 
 from __future__ import annotations
@@ -56,6 +58,25 @@ def exact_starts_ref(rows_u32: jnp.ndarray, ranks: jnp.ndarray) -> jnp.ndarray:
     need = ranks[None, :] - lo.sum(axis=0, keepdims=True)
     cum = jnp.cumsum(dups, axis=0) - dups
     return (lo + jnp.clip(need - cum, 0, dups)).T
+
+
+def compact_tiles_ref(masked: jnp.ndarray, starts: jnp.ndarray, tile: int,
+                      fill) -> jnp.ndarray:
+    """``tiles [G, tile]`` of ``[v, cap]`` rows and window starts ``[G + 1,
+    v]``: row ``g`` is the windows ``[starts[g, j], starts[g + 1, j])`` end
+    to end in bucket order, ``fill`` past their total — lane by lane."""
+    v, cap = masked.shape
+    lens = starts[1:] - starts[:-1]
+    cum = jnp.cumsum(lens, axis=1) - lens
+    slot = jnp.arange(tile, dtype=jnp.int32)
+    own = jax.vmap(lambda c: jnp.searchsorted(c, slot, side="right")
+                   )(cum).astype(jnp.int32) - 1
+    off = slot[None, :] - jnp.take_along_axis(cum, own, axis=1)
+    valid = off < jnp.take_along_axis(lens, own, axis=1)
+    pos = jnp.take_along_axis(starts[:-1], own, axis=1) + off
+    flat = own * cap + jnp.clip(pos, 0, cap - 1)
+    return jnp.where(valid, jnp.take(masked.reshape(-1), flat),
+                     jnp.asarray(fill, masked.dtype))
 
 
 def unpack_runs_ref(packed: np.ndarray, offsets: np.ndarray,

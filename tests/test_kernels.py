@@ -1,6 +1,7 @@
 """Per-kernel validation: shape/dtype sweeps in interpret mode against the
 pure-jnp oracles, plus hypothesis property tests."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -13,6 +14,7 @@ from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.kway_merge import ops as kway_merge_ops
 from repro.kernels.kway_merge import (
+    compact_tiles_ref,
     exact_starts_ref,
     kway_merge,
     kway_merge_ref,
@@ -583,11 +585,73 @@ def test_exact_starts_match_binary_search_oracle(cap, kind):
     rows = kway_merge_ops._to_biased_u32(jnp.asarray(masked))
     ranks = jnp.minimum(jnp.arange(-(-2 * v * cap // tile) + 1) * tile,
                         v * cap)
-    got = kway_merge_ops._exact_starts(rows, ranks)
+    got = kway_merge_ops._exact_starts(kway_merge_ops._fence_index(rows),
+                                       ranks, cap)
     want = exact_starts_ref(rows, ranks)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     np.testing.assert_array_equal(np.asarray(got).sum(axis=1),
                                   np.asarray(ranks))
+
+
+def _compact_case(kind, v, tile, rng):
+    """Masked ``[v, cap]`` rows (ascending, fill past the counts) and the
+    merge's ``rcap`` for one compaction edge case."""
+    fill = np.iinfo(np.int32).max
+    cap = 2 * tile
+    rcap = v * cap
+    if kind == "zero_windows":    # disjoint key ranges: most windows empty
+        rows = np.sort(rng.integers(0, 1000, size=(v, cap)), axis=1)
+        rows = rows + 1000 * np.arange(v)[:, None]
+    elif kind == "whole_tile":    # bucket 0 below the rest: whole tiles
+        cap = 3 * tile
+        rows = np.sort(rng.integers(10, 2**20, size=(v, cap)), axis=1)
+        rows[0] = np.arange(cap)
+        rcap = v * cap
+    elif kind == "partial_last":  # n_all < G·tile: the last tile is short
+        cap = tile + tile // 2 + 1
+        rows = np.sort(rng.integers(-2**31, fill, size=(v, cap)), axis=1)
+        rcap = v * cap + 3
+    elif kind == "ends_on_last_lane":   # interleaved full rows
+        rows = np.sort(rng.integers(-50, 50, size=(v, cap)), axis=1)
+    elif kind == "all_fill":      # every row but the last empty
+        rows = np.full((v, cap), fill)
+        rows[-1, :cap // 3] = np.arange(cap // 3)
+    else:                         # one key duplicated across every bucket
+        rows = np.full((v, cap), 7)
+    return rows.astype(np.int32), rcap
+
+
+@pytest.mark.parametrize("kind", ["zero_windows", "whole_tile",
+                                  "partial_last", "ends_on_last_lane",
+                                  "all_fill", "one_key"])
+@pytest.mark.parametrize("tile", [8, 256])
+@pytest.mark.parametrize("v", [1, 3, 16])
+def test_compact_tiles_match_lane_gather_oracle(kind, v, tile):
+    """The chip's compaction (block windows) equals the searchsorted lane
+    gather (``compact_tiles_ref``) lane for lane, on the exact splitters'
+    starts: empty windows, a bucket filling whole tiles, a short last
+    tile, windows ending on a row's last lane, all-fill rows, one key
+    everywhere."""
+    rows, rcap = _compact_case(kind, v, tile, np.random.default_rng(v * tile))
+    fill = np.iinfo(np.int32).max
+    v, cap = rows.shape
+    G = -(-rcap // tile)
+    ranks = jnp.minimum(jnp.arange(G + 1) * tile, v * cap)
+
+    @jax.jit
+    def both(rows):
+        index = kway_merge_ops._fence_index(
+            kway_merge_ops._to_biased_u32(rows))
+        starts = kway_merge_ops._exact_starts(index, ranks, cap)
+        got = kway_merge_ops._compact_blocks(index, starts, tile,
+                                             jnp.int32(fill))
+        return starts, got, compact_tiles_ref(rows, starts, tile, fill)
+
+    starts, got, want = both(jnp.asarray(rows))
+    if kind == "ends_on_last_lane":
+        st = np.asarray(starts)
+        assert ((st[1:] == cap) & (st[1:] > st[:-1])).any()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_psrs_bit_identical_across_merge_kernel():

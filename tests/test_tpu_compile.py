@@ -62,6 +62,35 @@ def _compile_text(fn, *specs):
     return jax.jit(fn).lower(*specs).compile().as_text()
 
 
+# The merge's temp bytes at the parent of the block-window compact gather
+# (commit 1f9096d), by this file's AOT compile (JAX 0.9.0, v5e:2x2): the
+# compiled merge may use at most 1.25 times as much.
+PARENT_MERGE_TEMP = {"kway_k4": 413_031_936, "packed_k1": 47_689_728,
+                     "packed_k4": 544_994_304}
+
+_GATHER = re.compile(
+    r"= \w+\[([\d,]*)\]\S* gather\(.*slice_sizes=\{([\d,]+)\}")
+
+
+def _assert_merge_gathers_blocks(compiled, k: int, rcap: int,
+                                 parent_temp: int) -> None:
+    """No gather of single lanes over the k·rcap output slots is left in
+    the merge (each window is read as whole blocks), and its transient
+    memory stays within 1.25 times the parent's."""
+    lane_gathers = []
+    for ln in compiled.as_text().splitlines():
+        m = _GATHER.search(ln)
+        if m and set(m.group(2).split(",")) == {"1"}:
+            n = 1
+            for d in filter(None, m.group(1).split(",")):
+                n *= int(d)
+            if n >= k * rcap:
+                lane_gathers.append(ln.strip()[:120])
+    assert not lane_gathers, lane_gathers
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 1.25 * parent_temp, (temp, parent_temp)
+
+
 def _assert_kernel(text: str, name: str) -> None:
     calls = [ln.strip() for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert calls, "no tpu_custom_call in the compiled program"
@@ -115,14 +144,18 @@ def test_kway_merge_compiles(one_chip):
     """The whole merge stage at the class-A cell's shape (NPB IS class A,
     v=16, k=4: [k, v, 2^19] receive buckets, rcap 2^20, tile 256),
     vmapped over the k resident contexts: the splitter search's fence
-    index and block gathers lower, and the tile merge stays a kernel
-    (``interpret=False``: the dispatch the chip's backend takes)."""
+    index and block gathers lower, the compact gather reads blocks and
+    not lanes, and the tile merge stays a kernel (``interpret=False``: the
+    dispatch the chip's backend takes)."""
     cap = (1 << 23) // V
     B = jax.ShapeDtypeStruct((K, V, cap), jnp.int32, sharding=one_chip)
     C = jax.ShapeDtypeStruct((K, V), jnp.int32, sharding=one_chip)
     merge = jax.vmap(lambda b, c: kway_merge(b, c, rcap=2 * cap, tile=256,
                                              fill=INT_MAX, interpret=False))
-    _assert_kernel(_compile_text(merge, B, C), "kway_merge")
+    compiled = jax.jit(merge).lower(B, C).compile()
+    _assert_kernel(compiled.as_text(), "kway_merge")
+    _assert_merge_gathers_blocks(compiled, K, 2 * cap,
+                                 PARENT_MERGE_TEMP["kway_k4"])
 
 
 def test_packed_delivery_compiles(one_chip):
@@ -151,7 +184,10 @@ def test_packed_merge_compiles(one_chip, k):
         return kway_merge(runs, counts, rcap=2 * n_v, tile=256,
                           fill=INT_MAX, interpret=False)[0]
 
-    _assert_kernel(_compile_text(jax.vmap(merge), R, O), "kway_merge")
+    compiled = jax.jit(jax.vmap(merge)).lower(R, O).compile()
+    _assert_kernel(compiled.as_text(), "kway_merge")
+    _assert_merge_gathers_blocks(compiled, k, 2 * n_v,
+                                 PARENT_MERGE_TEMP[f"packed_k{k}"])
 
 
 @pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
